@@ -58,6 +58,8 @@ print(json.dumps(rows))
 def run() -> list:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # placeholder CPU devices: the child never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     r = subprocess.run([sys.executable, "-c", CHILD], env=env,
                        capture_output=True, text=True, timeout=3000)

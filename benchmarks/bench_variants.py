@@ -265,6 +265,8 @@ def _run_child(child: str, timeout: int = 3000) -> list:
     JSON rows (last stdout line)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # placeholder CPU devices: the child never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     r = subprocess.run(
         [sys.executable, "-c", child], env=env,

@@ -50,7 +50,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.analyze.findings import Finding
-from repro.compat import shard_map
 from repro.core.engine import EngineConfig, build_step
 from repro.core.frontier import frontier_caps, payload_plane_words
 
@@ -145,11 +144,12 @@ def trace_step(
         return (out[0][None],) + out[1:]
 
     spec = P(axis_names)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=(spec,) + (P(),) * 7,
+        check_vma=False,  # as make_engine
     )
     s = jax.ShapeDtypeStruct
     args = (

@@ -284,7 +284,7 @@ def solve_with_engine_config(
     fn = compiled_engine(mesh, ecfg, pg.n_parts, pg.n_local)
     D0, T0, L0 = initial_state(pg, ecfg.processing, sources)
     D, it, commits, relax, classes, active, fallbacks, streak = fn(
-        pg.row_src, pg.col, pg.wgt, D0, T0, L0
+        *pg.on_mesh(mesh), D0, T0, L0
     )
     m = _finish_metrics(
         pg, ecfg, it, commits, relax, classes, active, fallbacks, streak
@@ -397,6 +397,7 @@ class Solver:
             pg = partition_graph(
                 graph, self.n_devices, partitioner=self.config.partition
             )
+            pg.on_mesh(self.mesh)  # the graph's one host-to-device copy
         self._pg_cache[id(graph)] = (graph, fp, pg)
         if len(self._pg_cache) > self._pg_cache_size:
             self._pg_cache.popitem(last=False)
@@ -443,7 +444,7 @@ class Solver:
                     self.mesh, ecfg, pg.n_parts, pg.n_local
                 )
                 with obs.span("solver.engine"):
-                    out = fn(pg.row_src, pg.col, pg.wgt, D0, T0, L0)
+                    out = fn(*pg.on_mesh(self.mesh), D0, T0, L0)
                 sol = self._pack(problem, pg, ecfg, *out)
             sp.set(supersteps=sol.metrics.supersteps,
                    converged=sol.metrics.converged)
@@ -508,7 +509,7 @@ class Solver:
         D0, T0, L0 = initial_state_batch(pg, p, items)
         with obs.span("solver.solve_batch", spec=self.config.name,
                       batch=B, batch_padded=Bpad):
-            D, *rest = fn(pg.row_src, pg.col, pg.wgt, D0, T0, L0)
+            D, *rest = fn(*pg.on_mesh(self.mesh), D0, T0, L0)
         D = np.asarray(D)  # (P, Bpad, n_local)
         rest = [np.asarray(r) for r in rest]  # each (Bpad,)
         return [
@@ -604,7 +605,7 @@ class Solver:
             sol = self._solve_quantized(problem, pg, ecfg, D0, T0, L0)
         else:
             fn = compiled_engine(self.mesh, ecfg, pg.n_parts, pg.n_local)
-            out = fn(pg.row_src, pg.col, pg.wgt, D0, T0, L0)
+            out = fn(*pg.on_mesh(self.mesh), D0, T0, L0)
             sol = self._pack(problem, pg, ecfg, *out)
         # account for the bootstrap sweep: one superstep's worth of
         # full-graph relaxation done host-side
@@ -682,8 +683,9 @@ class Solver:
         p = problem.processing_fn
         fn = compiled_engine(self.mesh, ecfg, pg.n_parts, pg.n_local)
         worst = np.float32(p.worst)
+        on_dev = pg.on_mesh(self.mesh)
         D, it, commits, relax, classes, active, fallbacks, streak = fn(
-            pg.row_src, pg.col, pg.wgt, D0, T0, L0
+            *on_dev, D0, T0, L0
         )
         it_t, commits_t = int(it), int(commits)
         relax_t, classes_t = int(relax), int(classes)
@@ -723,7 +725,7 @@ class Solver:
                 np.float32(0.0), np.float32(np.inf),
             ).astype(np.float32)
             D, it, commits, relax, classes, active, fallbacks, streak = fn(
-                pg.row_src, pg.col, pg.wgt, D0r, T0r, L0r
+                *on_dev, D0r, T0r, L0r
             )
             it_t += int(it)
             commits_t += int(commits)

@@ -63,7 +63,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.frontier import (
     PAYLOAD_MODES,
     compact_rows,
@@ -676,11 +675,15 @@ def make_engine(
                        active0, last_key0, streak0, limit, delta, force)
             return (out[0][None], out[1][None], out[2][None]) + out[3:]
 
-        sharded_seg = shard_map(
+        sharded_seg = jax.shard_map(
             local_seg,
             mesh=mesh,
             in_specs=(shard,) * 6 + (P(),) * 6,
             out_specs=(shard,) * 3 + (P(),) * 13,
+            # the superstep body mixes per-device and replicated values in
+            # while/cond carries and calls pallas_call, neither of which
+            # the varying-manual-axes checker types
+            check_vma=False,
         )
 
         @jax.jit
@@ -708,11 +711,12 @@ def make_engine(
             out = vloop(row_src[0], col[0], wgt[0], D[0], T[0], L[0])
             return (out[0][None],) + out[1:]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(shard, shard, shard, shard, shard, shard),
         out_specs=(shard,) + (P(),) * 7,
+        check_vma=False,  # as for the segment engine
     )
 
     @jax.jit

@@ -57,7 +57,9 @@ import dataclasses
 import math
 from typing import Optional
 
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.graph.formats import Graph, CSR, coo_to_csr, INF
 
@@ -275,6 +277,24 @@ class PartitionedGraph:
                 inv[self.perm] = np.arange(self.n, dtype=np.int64)
             self._inv_perm = inv
         return inv
+
+    def on_mesh(self, mesh) -> tuple:
+        """``(row_src, col, wgt)`` as device arrays on ``mesh``, the
+        rank axis split over all of its axes (the engine's ``shard_map``
+        in-spec).  Placed once per mesh and memoized, so repeat solves
+        copy no graph bytes to the device; the numpy buffers stay for
+        host-side readers.  Nothing mutates them in place: a changed
+        graph is partitioned anew, and so placed anew."""
+        placed = self.__dict__.setdefault("_placed", {})
+        arrs = placed.get(mesh)
+        if arrs is None:
+            sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names))
+            arrs = tuple(
+                jax.device_put(a, sharding)
+                for a in (self.row_src, self.col, self.wgt)
+            )
+            placed[mesh] = arrs
+        return arrs
 
     # -- the owner-mapping seam ---------------------------------------
 

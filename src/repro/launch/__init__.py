@@ -3,7 +3,7 @@ driver and the SSSP driver.  (dryrun must be run as a module so its
 XLA device-count flag precedes jax initialization.)"""
 
 from repro.launch.mesh import (
-    make_cpu_topology, make_production_mesh, make_topology,
+    make_local_topology, make_production_mesh, make_topology,
 )
 
-__all__ = ["make_cpu_topology", "make_production_mesh", "make_topology"]
+__all__ = ["make_local_topology", "make_production_mesh", "make_topology"]

@@ -65,7 +65,7 @@ def cmd_record(args) -> int:
     import numpy as np
 
     from repro.api import Problem, SingleSource, Solver
-    from repro.launch.mesh import make_cpu_topology
+    from repro.launch.mesh import make_local_topology
     from repro.launch.sssp import build_graph
     from repro.obs import (
         MetricsRegistry, Tracer, use_tracer,
@@ -73,7 +73,7 @@ def cmd_record(args) -> int:
     )
 
     g = build_graph(args.graph, args.scale, args.seed)
-    topo = make_cpu_topology()
+    topo = make_local_topology()
     base = Solver(args.spec, mesh=topo.mesh)
     if base.config.trace:
         print("error: pass the UNTRACED spec; record adds /trace itself",

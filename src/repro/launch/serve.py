@@ -79,15 +79,16 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.api import Problem, SingleSource, Solver
-    from repro.launch.mesh import make_cpu_topology
+    from repro.launch.mesh import make_local_topology, use_compile_cache
     from repro.launch.sssp import build_graph
     from repro.serve import (
         EdgeUpdate, LandmarkIndex, Router, SolutionCache, UpdateFeed,
         serve_latency_stats,
     )
 
+    use_compile_cache()
     g = build_graph(args.graph, args.scale, args.seed)
-    topo = make_cpu_topology()
+    topo = make_local_topology()
     solver = Solver(args.spec, mesh=topo.mesh)
     print(f"[serve] {g.name}: n={g.n} m={g.m} spec={solver.config.name} "
           f"devices={solver.n_devices}")

@@ -116,14 +116,19 @@ def main() -> None:
             print(line)
         return
 
+    import jax
+
     from repro.api import (
         EveryVertex, Problem, SingleSource, Solver, SolverConfig,
     )
     from repro.core import dijkstra_reference, model_time_s
-    from repro.launch.mesh import make_cpu_topology
+    from repro.launch.mesh import (
+        compile_clock, make_local_topology, use_compile_cache,
+    )
 
+    use_compile_cache()
     g = build_graph(args.graph, args.scale, args.seed)
-    topo = make_cpu_topology()
+    topo = make_local_topology()
 
     spec = args.spec or f"{args.root}+{args.variant}/{args.exchange}"
     overrides = dict(chunk_size=args.chunk)
@@ -155,21 +160,26 @@ def main() -> None:
             for v in args.sources
         ]
 
-    t0 = time.time()
-    if cfg.adapt is not None and len(problems) > 1:
-        # solve_batch rejects adaptive specs (one shared controller
-        # schedule would steer every lane); solve them one at a time
-        sols = [solver.solve(pb) for pb in problems]
-    else:
-        sols = solver.solve_batch(problems)
-    wall = time.time() - t0
+    with compile_clock() as cc:
+        t0 = time.perf_counter()
+        if cfg.adapt is not None and len(problems) > 1:
+            # solve_batch rejects adaptive specs (one shared controller
+            # schedule would steer every lane); solve them one at a time
+            sols = [solver.solve(pb) for pb in problems]
+        else:
+            sols = solver.solve_batch(problems)
+        # solutions are host arrays: the device work has finished
+        wall = time.perf_counter() - t0
     print(f"[sssp] spec={cfg.name} batch={len(problems)}")
     for label, sol in zip(labels, sols):
         m = sol.metrics
         print(f"[sssp] {label} {m}")
         print(f"[sssp] cost_model(256 chips)={model_time_s(m, 256)*1e3:.2f}ms "
               f"reached={int(np.isfinite(sol.state).sum())}/{g.n}")
-    print(f"[sssp] cpu_wall={wall:.2f}s total")
+    dev = jax.devices()[0]
+    print(f"[sssp] {dev.platform} ({dev.device_kind} x{jax.device_count()}): "
+          f"wall={wall:.3f}s compile={cc.seconds:.3f}s "
+          f"run={wall - cc.seconds:.3f}s")
 
     if args.verify and args.problem == "sssp":
         bad = 0

@@ -35,7 +35,7 @@ def main() -> None:
 
     from repro.configs import get_arch
     from repro.data import lm_batch
-    from repro.launch.mesh import make_cpu_topology
+    from repro.launch.mesh import make_local_topology
     from repro.models import lm as lm_mod
     from repro.train import (
         AdamWConfig, Checkpointer, TrainConfig, build_train_step,
@@ -47,7 +47,7 @@ def main() -> None:
         raise SystemExit("train driver currently targets the LM family; "
                          "use examples/gnn_train.py for GNNs")
     cfg = mod.make_config(reduced=args.reduced)
-    topo = make_cpu_topology()
+    topo = make_local_topology()
     tc = TrainConfig(
         adamw=AdamWConfig(lr=args.lr),
         microbatches=args.microbatches,

@@ -90,11 +90,11 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
              if os.path.exists(args.cache) else TunedSpecCache())
 
     if args.search:
-        from repro.launch.mesh import make_cpu_topology
+        from repro.launch.mesh import make_local_topology
         from repro.launch.sssp import build_graph
 
         g = build_graph(args.graph, args.scale, args.seed)
-        topo = make_cpu_topology()
+        topo = make_local_topology()
         tuner = AutoTuner(
             topo.mesh,
             objective=args.objective,

@@ -17,7 +17,6 @@ import contextvars
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.common import fan_in_init
 
 # §Perf (dimenet/ogb_products): when set, every segment-reduce output
@@ -106,7 +105,7 @@ def scatter_sum_owner_aligned(values, index, n):
         return jax.ops.segment_sum(v, local_ids, num_segments=n_loc)
 
     trail = tuple([None] * (values.ndim - 1))
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=topo.mesh,
         in_specs=(P(axes, *trail), P(axes)),
         out_specs=P(axes, *trail),

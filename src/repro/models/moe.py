@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.common import Topology, swiglu
 
 
@@ -159,7 +158,7 @@ def moe_ffn(
         aux = jax.lax.pmean(aux, topo.axis_names)
         return out.reshape(xb.shape), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn,
         mesh=topo.mesh,
         in_specs=(

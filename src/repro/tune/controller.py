@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.core.engine import EngineConfig
@@ -96,7 +97,10 @@ def run_adaptive(
         cap = None
     force = 0
 
-    D, T, L = D0, T0, L0
+    on_dev = pg.on_mesh(mesh)
+    # segments hand the state back sharded on the mesh; start it so,
+    # or the first engine is traced again for the changed input type
+    D, T, L = (jax.device_put(x, on_dev[0].sharding) for x in (D0, T0, L0))
     active = int(np.sum(np.asarray(p.better(T0, D0))))
     last_key = np.float32(np.nan)
     streak = 0
@@ -122,7 +126,7 @@ def run_adaptive(
             limit = min(Wn, ecfg.max_iters - it_total)
             t0_seg = obs.now()
             out = fn(
-                pg.row_src, pg.col, pg.wgt, D, T, L,
+                *on_dev, D, T, L,
                 np.int32(active), np.float32(last_key), np.int32(streak),
                 np.int32(limit),
                 np.float32(delta if delta is not None else np.nan),

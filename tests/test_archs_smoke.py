@@ -140,9 +140,9 @@ def test_all_cells_constructible_single_device():
     trees are structurally compatible (full lowering happens in the
     512-device dry-run)."""
     from repro.configs import all_cells
-    from repro.launch.mesh import make_cpu_topology
+    from repro.launch.mesh import make_local_topology
 
-    topo = make_cpu_topology(1)
+    topo = make_local_topology(1)
     built = 0
     for arch, cell in all_cells():
         prog = get_arch(arch).make_cell(cell, topo)

@@ -50,8 +50,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 mesh = jax.make_mesh((4,), ("d",))
 def f(x):
-    from repro.compat import shard_map
-    return shard_map(lambda y: jax.lax.psum(y, "d"), mesh=mesh,
+    return jax.shard_map(lambda y: jax.lax.psum(y, "d"), mesh=mesh,
                      in_specs=P("d"), out_specs=P())(x)
 xs = jax.ShapeDtypeStruct((4096,), jnp.float32)
 with mesh:

@@ -12,6 +12,7 @@ import sys
 import jax
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec
 
 import repro.api as api
 from repro.api import Problem, SingleSource, Solver, batch_bucket
@@ -325,6 +326,34 @@ def test_feed_improving_drop_warm_refresh_bit_identical(solver):
         assert close(dijkstra_reference(g, key[1]), sol.state)
         cold_steps += cold.metrics.supersteps
     assert res.warm_supersteps < cold_steps  # strictly fewer supersteps
+
+
+def test_feed_update_refreshes_device_graph(solver):
+    """The partitioned graph is placed on the solver's mesh once, with
+    the engine's sharding; an update places the changed graph anew, so
+    a warm restart reads the new weight and not a stale device copy."""
+    g = fresh_graph()
+    pg0 = solver.partition(g)
+    placed = pg0.on_mesh(solver.mesh)
+    want = NamedSharding(solver.mesh, PartitionSpec(solver.mesh.axis_names))
+    for dev, host in zip(placed, (pg0.row_src, pg0.col, pg0.wgt)):
+        assert isinstance(dev, jax.Array)
+        assert dev.sharding.is_equivalent_to(want, dev.ndim)
+        assert np.array_equal(np.asarray(dev), host)
+    assert solver.partition(g).on_mesh(solver.mesh) is placed  # once
+
+    e = 17
+    u, v = int(g.src[e]), int(g.dst[e])
+    new_w = np.float32(float(g.weight[e]) * 0.25)
+    UpdateFeed(g, solver).apply(EdgeUpdate(u, v, float(new_w)))
+    pg1 = solver.partition(g)
+    wgt1 = np.asarray(pg1.on_mesh(solver.mesh)[2])
+    assert np.array_equal(wgt1, pg1.wgt)
+    rank, slot = (int(x) for x in pg1.owner_slot(u))
+    edge = ((pg1.row_src[rank] == slot)[:, None]
+            & (pg1.col[rank] == int(pg1.padded_id(v))))
+    assert wgt1[rank][edge].min() == new_w
+    assert np.asarray(placed[2])[rank][edge].min() > new_w
 
 
 def test_feed_insertion_is_improving(solver):

@@ -1,0 +1,107 @@
+"""The engine compiled for a described TPU v5e (no chip attached).
+
+The TPU compiler refuses what the CPU backend accepts: tiling it cannot
+lay out, programs that do not fit the device's memory.  These tests
+compile ``make_engine``'s default (``ref``) program at real graph
+shapes for one v5e chip and for a 2x2 mesh of four, and hold each to
+the chip's 16 GiB of HBM.  Nothing runs; results are checked elsewhere.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, so a worker that loads it at
+collection would stop the others from collecting the same tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from repro.core.engine import EngineConfig, make_engine
+
+V5E_HBM_BYTES = 16 * 2**30
+
+# rmat1(scale=20, seed=0) under partition_graph(g, 1): its stacked ELL
+# row count at the default width (graph500 Kronecker, edge factor 16)
+SCALE20 = dict(n_local=1 << 20, rows=1_416_878, width=64)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler, or no such topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def compile_engine(devices, exchange, n_local, rows, width, batch=None):
+    """AOT-compile the engine over a 1-D mesh of ``devices`` for one
+    rank's shapes; return the compiled program."""
+    n_parts = len(devices)
+    mesh = Mesh(np.array(devices), ("data",))
+    ecfg = EngineConfig("delta:5+buffer", exchange=exchange)
+    fn = make_engine(dict(n_parts=n_parts, n_local=n_local), mesh, ecfg,
+                     batch=batch)
+    shard = NamedSharding(mesh, PartitionSpec(("data",)))
+    state = (n_parts, n_local + 1) if batch is None else \
+        (n_parts, batch, n_local + 1)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=shard)
+
+    args = (
+        arg((n_parts, rows), jnp.int32),
+        arg((n_parts, rows, width), jnp.int32),
+        arg((n_parts, rows, width), jnp.float32),
+        arg(state, jnp.float32),
+        arg(state, jnp.float32),
+        arg(state, jnp.float32),
+    )
+    return fn.lower(*args).compile()
+
+
+def device_bytes(compiled) -> int:
+    """Arguments plus temporaries on each device."""
+    mem = compiled.memory_analysis()
+    return int(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("exchange", ["a2a", "pmin"])
+def test_scale20_engine_fits_one_chip(topo, exchange):
+    compiled = compile_engine(topo.devices[:1], exchange, **SCALE20)
+    used = device_bytes(compiled)
+    # the graph alone is R * (1 + 2W) words
+    graph = 4 * SCALE20["rows"] * (1 + 2 * SCALE20["width"])
+    assert graph <= used <= V5E_HBM_BYTES
+
+
+def test_sparse_engine_fits_one_chip(topo):
+    # rmat1(16, seed=0) shapes: the sparse program at scale 20 takes
+    # about half a minute to compile
+    compiled = compile_engine(
+        topo.devices[:1], "sparse", n_local=1 << 16, rows=85_904, width=64
+    )
+    assert device_bytes(compiled) <= V5E_HBM_BYTES
+
+
+def test_a2a_engine_on_four_chips(topo):
+    # rmat1(20) over four ranks: a quarter of the rows, plus the
+    # imbalance a 4-way block split shows at scale 16
+    compiled = compile_engine(
+        topo.devices, "a2a", n_local=1 << 18, rows=360_000, width=64
+    )
+    assert device_bytes(compiled) <= V5E_HBM_BYTES
+    assert "all-to-all" in compiled.as_text()
