@@ -1,0 +1,33 @@
+"""Published peaks of each accelerator the benchmark may run on, keyed
+by JAX's ``device_kind``.  A device that is not listed is an error, so
+no roofline share is ever computed against a guessed peak."""
+
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture):
+    # per chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2 at
+    # 819 GB/s, 1,600 Gbit/s inter-chip interconnect
+    "TPU v5 lite": {
+        "hbm_bytes_per_s": 819e9,
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes": 16e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+class UnknownDevice(LookupError):
+    pass
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
