@@ -385,7 +385,8 @@ class Solver:
                     "repro.graph.partition_graph or pass the raw Graph"
                 )
             return graph
-        fp = graph_fingerprint(graph)
+        with obs.span("solver.fingerprint", n=graph.n, m=graph.m):
+            fp = graph_fingerprint(graph)
         hit = self._pg_cache.get(id(graph))
         if hit is not None and hit[0] is graph and hit[1] == fp:
             self._pg_cache.move_to_end(id(graph))
@@ -434,7 +435,8 @@ class Solver:
             pg = self.partition(problem.graph)
             p = problem.processing_fn
             ecfg = self.config.engine_config(p)
-            D0, T0, L0 = initial_state(pg, p, problem.source_items())
+            with obs.span("solver.initial_state"):
+                D0, T0, L0 = initial_state(pg, p, problem.source_items())
             if ecfg.adapt_window > 0:
                 sol = self._solve_adaptive(problem, pg, ecfg, D0, T0, L0)
             elif ecfg.payload != "exact":
@@ -443,8 +445,12 @@ class Solver:
                 fn = compiled_engine(
                     self.mesh, ecfg, pg.n_parts, pg.n_local
                 )
+                # the call returns at dispatch; the fetch waits for the
+                # device, so the span ends with the result on the host
                 with obs.span("solver.engine"):
-                    out = fn(*pg.on_mesh(self.mesh), D0, T0, L0)
+                    out = jax.device_get(
+                        fn(*pg.on_mesh(self.mesh), D0, T0, L0)
+                    )
                 sol = self._pack(problem, pg, ecfg, *out)
             sp.set(supersteps=sol.metrics.supersteps,
                    converged=sol.metrics.converged)
@@ -652,13 +658,8 @@ class Solver:
             st["retraces"] += report.retraces
             st["cap_growths"] += report.cap_growths
         padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
-        return Solution(
-            state=pg.unpermute(padded.reshape(-1)),
-            metrics=m,
-            problem=problem,
-            config=self.config,
-            padded=padded,
-            pg=pg,
+        return self._solution(
+            problem, pg, padded, m,
             trace=recorder.finish(m) if recorder is not None else None,
         )
 
@@ -743,14 +744,7 @@ class Solver:
         m.supersteps += verifies
         m.repair_sweeps = sweeps
         padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
-        return Solution(
-            state=pg.unpermute(padded.reshape(-1)),
-            metrics=m,
-            problem=problem,
-            config=self.config,
-            padded=padded,
-            pg=pg,
-        )
+        return self._solution(problem, pg, padded, m)
 
     def _pack(
         self, problem, pg, ecfg, D, it, commits, relax, classes,
@@ -761,13 +755,21 @@ class Solver:
             pg, ecfg, it, commits, relax, classes, active, fallbacks,
             overflow_streak,
         )
+        return self._solution(problem, pg, padded, m)
+
+    def _solution(self, problem, pg, padded, metrics, trace=None) -> Solution:
+        """The Solution of a padded (P, n_local) state, mapped back to
+        the original vertex ids."""
+        with obs.span("solver.unpermute", n=pg.n):
+            state = pg.unpermute(padded.reshape(-1))
         return Solution(
-            state=pg.unpermute(padded.reshape(-1)),
-            metrics=m,
+            state=state,
+            metrics=metrics,
             problem=problem,
             config=self.config,
             padded=padded,
             pg=pg,
+            trace=trace,
         )
 
 

@@ -31,6 +31,13 @@ One loop iteration = one superstep:
   6. fold into T, count pending via psum ⇒ termination detection
      (active-work count, paper §II).
 
+Each step runs under a ``jax.named_scope``, so every op of the loop
+body carries its phase in its HLO ``op_name`` and a profiler trace can
+be split by phase: ``eligibility`` (1-3), ``compact`` (the sparse
+path's row compaction), ``relax`` (4; ``relax/push`` and
+``relax/dense``), ``exchange`` (5) and ``vote`` (6).  Scopes are
+metadata only: the compiled program is otherwise unchanged.
+
 Frontier-sparse path (``exchange='sparse'`` / ``'auto'``): instead of
 relaxing all R rows and moving O(|V|) floats, the eligible rows are
 compacted into a fixed-capacity index list (cap F, the
@@ -269,37 +276,38 @@ def build_step(
         # local reduction, drain (TopK) -> local top-B.  The first
         # annotation is the AGM root; its class key feeds the
         # distinct-classes metric.
-        pending = p.better(T, D)
-        eligible = pending
-        kmin = INF
-        for ai, (lvl, o) in enumerate(hier.annotations):
-            if adaptive and ai == 0 and isinstance(o, DeltaStepping):
-                # dynamic bucket width: the same op sequence as
-                # DeltaStepping.class_key with delta a traced scalar —
-                # bit-identical to the static engine whenever the
-                # scalar equals the spec's constant, retunable by the
-                # controller without retracing
-                raw_key = jnp.floor(T / delta_dyn)
-            else:
-                raw_key = o.class_key(T, L)
-            key = jnp.where(eligible, raw_key, INF)
-            if lvl in ("global", "pod"):
-                axes = all_axes if lvl == "global" else pod_axes
-                m = jnp.min(key)
-                if axes:
-                    m = jax.lax.pmin(m, axes)
-                eligible = eligible & (key == m)
-                if lvl == "global":
-                    kmin = m
-            elif getattr(o, "drain", None) is not None:  # local top-B drain
-                B = min(o.drain, n_local)
-                kth = -jax.lax.top_k(-key, B)[0][B - 1]
-                eligible = eligible & (key <= kth)
-            else:  # device/chunk minimal class, collective-free
-                eligible = eligible & (key == jnp.min(key))
+        with jax.named_scope("eligibility"):
+            pending = p.better(T, D)
+            eligible = pending
+            kmin = INF
+            for ai, (lvl, o) in enumerate(hier.annotations):
+                if adaptive and ai == 0 and isinstance(o, DeltaStepping):
+                    # dynamic bucket width: the same op sequence as
+                    # DeltaStepping.class_key with delta a traced scalar —
+                    # bit-identical to the static engine whenever the
+                    # scalar equals the spec's constant, retunable by the
+                    # controller without retracing
+                    raw_key = jnp.floor(T / delta_dyn)
+                else:
+                    raw_key = o.class_key(T, L)
+                key = jnp.where(eligible, raw_key, INF)
+                if lvl in ("global", "pod"):
+                    axes = all_axes if lvl == "global" else pod_axes
+                    m = jnp.min(key)
+                    if axes:
+                        m = jax.lax.pmin(m, axes)
+                    eligible = eligible & (key == m)
+                    if lvl == "global":
+                        kmin = m
+                elif getattr(o, "drain", None) is not None:  # local top-B drain
+                    B = min(o.drain, n_local)
+                    kth = -jax.lax.top_k(-key, B)[0][B - 1]
+                    eligible = eligible & (key <= kth)
+                else:  # device/chunk minimal class, collective-free
+                    eligible = eligible & (key == jnp.min(key))
 
-        # ---- 3. commit (atomic monotone state update) -----------------
-        D = jnp.where(eligible, T, D)
+            # ---- 3. commit (atomic monotone state update) -------------
+            D = jnp.where(eligible, T, D)
 
         # ---- 4. relax out-edges of eligible vertices (ELL) ------------
         def level_scatter(cols, cands, lvl_cands, C):
@@ -315,6 +323,7 @@ def build_step(
                 jnp.where(win, lvl_cands, INF).reshape(-1)
             )[:n_pad]
 
+        @jax.named_scope("dense")
         def relax_dense(_):
             """Pull sweep over all R virtual rows (masked)."""
             if is_min:
@@ -344,9 +353,11 @@ def build_step(
             return C, level_scatter(col, cand, lvl_cand, C)
 
         if sparse_mode:
-            elig_rows = eligible[row_src]
-            f_idx, f_cnt, row_overflow = compact_rows(elig_rows, row_cap)
+            with jax.named_scope("compact"):
+                elig_rows = eligible[row_src]
+                f_idx, f_cnt, row_overflow = compact_rows(elig_rows, row_cap)
 
+            @jax.named_scope("push")
             def relax_push(_):
                 """Push mode: gather only the F eligible virtual rows
                 (kernels/relax_push is the TPU realization of the
@@ -394,11 +405,13 @@ def build_step(
                 )
                 return C, level_scatter(colg, cand, lvl_cand, C)
 
-            # local decision, collective-free branches: a device whose
-            # frontier overflows F sweeps densely on its own
-            C, CL = jax.lax.cond(row_overflow, relax_dense, relax_push, None)
-        else:
-            C, CL = relax_dense(None)
+        with jax.named_scope("relax"):
+            if sparse_mode:
+                # local decision, collective-free branches: a device
+                # whose frontier overflows F sweeps densely on its own
+                C, CL = jax.lax.cond(row_overflow, relax_dense, relax_push, None)
+            else:
+                C, CL = relax_dense(None)
 
         # ---- 5. exchange candidates to owner devices ------------------
         # Each exchange returns (mine, mineL): the combined (n_local,)
@@ -444,132 +457,134 @@ def build_step(
                 mineL = jnp.zeros_like(mine)
             return mine, mineL
 
-        if cfg.exchange == "pmin":
-            mine, mineL = exchange_pmin(None)
-        elif cfg.exchange == "a2a":
-            mine, mineL = exchange_a2a(None)
-        elif cfg.exchange == "auto" and payload_plane_words(
-            slot_cap, use_level, cfg.payload
-        ) >= nplanes * n_local:
-            # static shortcut: at these capacities the sparse payload
-            # can never move fewer words than the dense reduce-scatter
-            # (payload words ≥ planes·n_local), so 'auto' resolves to
-            # dense at trace time — no compaction, no decision collective
-            mine, mineL = exchange_a2a(None)
-            fallbacks = fallbacks + 1
-        else:  # 'sparse' | 'auto'
-            extra = [(CL, INF)] if use_level else []
-            payload, ex_overflow = sparse_payload(
-                C, extra, n_parts, slot_cap, worst, payload=cfg.payload
-            )
-            cap_ok = jnp.logical_not(ex_overflow)
-            ok = cap_ok
-            if cfg.exchange == "auto":
-                ok = ok & (active_prev <= jnp.int32(auto_thresh))
+        with jax.named_scope("exchange"):
+            if cfg.exchange == "pmin":
+                mine, mineL = exchange_pmin(None)
+            elif cfg.exchange == "a2a":
+                mine, mineL = exchange_a2a(None)
+            elif cfg.exchange == "auto" and payload_plane_words(
+                slot_cap, use_level, cfg.payload
+            ) >= nplanes * n_local:
+                # static shortcut: at these capacities the sparse payload
+                # can never move fewer words than the dense reduce-scatter
+                # (payload words ≥ planes·n_local), so 'auto' resolves to
+                # dense at trace time — no compaction, no decision collective
+                mine, mineL = exchange_a2a(None)
+                fallbacks = fallbacks + 1
+            else:  # 'sparse' | 'auto'
+                extra = [(CL, INF)] if use_level else []
+                payload, ex_overflow = sparse_payload(
+                    C, extra, n_parts, slot_cap, worst, payload=cfg.payload
+                )
+                cap_ok = jnp.logical_not(ex_overflow)
+                ok = cap_ok
+                if cfg.exchange == "auto":
+                    ok = ok & (active_prev <= jnp.int32(auto_thresh))
+                if adaptive:
+                    # controller override: 1 forces sparse (the capacity
+                    # veto still applies — exactness over preference),
+                    # 2 forces dense, 0 keeps the mode's own heuristic
+                    ok = jnp.where(force_ex == jnp.int32(1), cap_ok, ok)
+                    ok = ok & jnp.logical_not(force_ex == jnp.int32(2))
+                # the all_to_all shapes differ between branches, so every
+                # rank must take the same one: agree globally (pmin of the
+                # local votes — a rank whose buckets overflow vetoes).
+                # Votes are pinned to strong int32: a weak-typed Python
+                # scalar here would thread promotion through the carry
+                # (jaxpr lint rule 'weak-scalar').  Lane 1 piggybacks the
+                # capacity-overflow vote for the consecutive-overflow
+                # streak, so the streak costs no extra collective round.
+                over_local = row_overflow | ex_overflow
+                votes = jnp.stack([
+                    jnp.where(ok, jnp.int32(1), jnp.int32(0)),
+                    jnp.where(over_local, jnp.int32(0), jnp.int32(1)),
+                ])
+                gvote = jax.lax.pmin(votes, all_axes)
+                use_sp = gvote[0] > jnp.int32(0)
+                overflow_g = gvote[1] == jnp.int32(0)
+
+                def exchange_sparse(_):
+                    recv = jax.lax.all_to_all(
+                        payload, all_axes, split_axis=0, concat_axis=0,
+                        tiled=True,
+                    )
+                    mine, mineL = unpack_combine(
+                        recv, n_local, slot_cap, is_min, worst, use_level,
+                        payload=cfg.payload,
+                    )
+                    if mineL is None:
+                        mineL = jnp.zeros_like(mine)
+                    return mine, mineL
+
+                mine, mineL = jax.lax.cond(
+                    use_sp, exchange_sparse, exchange_a2a, None
+                )
+                fallbacks = fallbacks + jnp.where(
+                    use_sp, jnp.int32(0), jnp.int32(1)
+                )
+                sp_used = jnp.where(use_sp, jnp.int32(1), jnp.int32(0))
+                streak = jnp.where(
+                    overflow_g, streak + jnp.int32(1), jnp.int32(0)
+                )
+                max_streak = jnp.maximum(max_streak, streak)
+
+        # ---- 6. fold into pending state T, votes ----------------------
+        with jax.named_scope("vote"):
+            mine_ext = jnp.concatenate([mine, jnp.array([worst])])
+            improved = p.better(mine_ext, T)
+            T = jnp.where(improved, mine_ext, T)
+            if use_level:
+                mineL_ext = jnp.concatenate([mineL, jnp.array([INF])])
+                L = jnp.where(improved, mineL_ext, L)
+
             if adaptive:
-                # controller override: 1 forces sparse (the capacity
-                # veto still applies — exactness over preference),
-                # 2 forces dense, 0 keeps the mode's own heuristic
-                ok = jnp.where(force_ex == jnp.int32(1), cap_ok, ok)
-                ok = ok & jnp.logical_not(force_ex == jnp.int32(2))
-            # the all_to_all shapes differ between branches, so every
-            # rank must take the same one: agree globally (pmin of the
-            # local votes — a rank whose buckets overflow vetoes).
-            # Votes are pinned to strong int32: a weak-typed Python
-            # scalar here would thread promotion through the carry
-            # (jaxpr lint rule 'weak-scalar').  Lane 1 piggybacks the
-            # capacity-overflow vote for the consecutive-overflow
-            # streak, so the streak costs no extra collective round.
-            over_local = row_overflow | ex_overflow
-            votes = jnp.stack([
-                jnp.where(ok, jnp.int32(1), jnp.int32(0)),
-                jnp.where(over_local, jnp.int32(0), jnp.int32(1)),
-            ])
-            gvote = jax.lax.pmin(votes, all_axes)
-            use_sp = gvote[0] > jnp.int32(0)
-            overflow_g = gvote[1] == jnp.int32(0)
-
-            def exchange_sparse(_):
-                recv = jax.lax.all_to_all(
-                    payload, all_axes, split_axis=0, concat_axis=0,
-                    tiled=True,
+                # one stacked psum publishes the whole metrics window row
+                # (eligible class size, eligible ELL rows, live edge
+                # relaxations) in a single collective round
+                live = eligible[row_src][:, None] & (wgt < INF)
+                if sparse_mode:
+                    erows = f_cnt
+                else:
+                    erows = jnp.sum(eligible[row_src].astype(jnp.int32))
+                sums = jax.lax.psum(
+                    jnp.stack([
+                        jnp.sum(eligible.astype(jnp.int32)),
+                        erows,
+                        jnp.sum(live.astype(jnp.int32)),
+                    ]),
+                    all_axes,
                 )
-                mine, mineL = unpack_combine(
-                    recv, n_local, slot_cap, is_min, worst, use_level,
-                    payload=cfg.payload,
+                commits = commits + sums[0]
+                relax = relax + sums[2]
+                classes = classes + (kmin != last_key).astype(jnp.int32)
+            elif cfg.collect_metrics:
+                live = eligible[row_src][:, None] & (wgt < INF)
+                commits = commits + jax.lax.psum(
+                    jnp.sum(eligible.astype(jnp.int32)), all_axes
                 )
-                if mineL is None:
-                    mineL = jnp.zeros_like(mine)
-                return mine, mineL
+                relax = relax + jax.lax.psum(
+                    jnp.sum(live.astype(jnp.int32)), all_axes
+                )
+                classes = classes + (kmin != last_key).astype(jnp.int32)
 
-            mine, mineL = jax.lax.cond(
-                use_sp, exchange_sparse, exchange_a2a, None
+            # termination detection: global count of pending workitems
+            # (paper §II "active work"); kept in the carry so the while
+            # predicate stays collective-free.
+            pending_new = p.better(T, D)
+            active = jax.lax.psum(
+                jnp.sum(pending_new.astype(jnp.int32)), all_axes
             )
-            fallbacks = fallbacks + jnp.where(
-                use_sp, jnp.int32(0), jnp.int32(1)
-            )
-            sp_used = jnp.where(use_sp, jnp.int32(1), jnp.int32(0))
-            streak = jnp.where(
-                overflow_g, streak + jnp.int32(1), jnp.int32(0)
-            )
-            max_streak = jnp.maximum(max_streak, streak)
 
-        # ---- 6. fold into pending state T ------------------------------
-        mine_ext = jnp.concatenate([mine, jnp.array([worst])])
-        improved = p.better(mine_ext, T)
-        T = jnp.where(improved, mine_ext, T)
-        if use_level:
-            mineL_ext = jnp.concatenate([mineL, jnp.array([INF])])
-            L = jnp.where(improved, mineL_ext, L)
-
-        if adaptive:
-            # one stacked psum publishes the whole metrics window row
-            # (eligible class size, eligible ELL rows, live edge
-            # relaxations) in a single collective round
-            live = eligible[row_src][:, None] & (wgt < INF)
-            if sparse_mode:
-                erows = f_cnt
-            else:
-                erows = jnp.sum(eligible[row_src].astype(jnp.int32))
-            sums = jax.lax.psum(
-                jnp.stack([
-                    jnp.sum(eligible.astype(jnp.int32)),
-                    erows,
-                    jnp.sum(live.astype(jnp.int32)),
-                ]),
-                all_axes,
-            )
-            commits = commits + sums[0]
-            relax = relax + sums[2]
-            classes = classes + (kmin != last_key).astype(jnp.int32)
-        elif cfg.collect_metrics:
-            live = eligible[row_src][:, None] & (wgt < INF)
-            commits = commits + jax.lax.psum(
-                jnp.sum(eligible.astype(jnp.int32)), all_axes
-            )
-            relax = relax + jax.lax.psum(
-                jnp.sum(live.astype(jnp.int32)), all_axes
-            )
-            classes = classes + (kmin != last_key).astype(jnp.int32)
-
-        # termination detection: global count of pending workitems
-        # (paper §II "active work"); kept in the carry so the while
-        # predicate stays collective-free.
-        pending_new = p.better(T, D)
-        active = jax.lax.psum(
-            jnp.sum(pending_new.astype(jnp.int32)), all_axes
-        )
-
-        if adaptive:
-            pend_w = pend_w.at[it].set(active)
-            elig_w = elig_w.at[it].set(sums[0])
-            rows_w = rows_w.at[it].set(sums[1])
-            sparse_w = sparse_w.at[it].set(sp_used)
-            return (D, T, L, it + 1, active, commits, relax, classes,
-                    kmin, fallbacks, streak, max_streak,
-                    pend_w, elig_w, rows_w, sparse_w)
-        return (D, T, L, it + 1, active, commits, relax, classes, kmin,
-                fallbacks, streak, max_streak)
+            if adaptive:
+                pend_w = pend_w.at[it].set(active)
+                elig_w = elig_w.at[it].set(sums[0])
+                rows_w = rows_w.at[it].set(sums[1])
+                sparse_w = sparse_w.at[it].set(sp_used)
+                return (D, T, L, it + 1, active, commits, relax, classes,
+                        kmin, fallbacks, streak, max_streak,
+                        pend_w, elig_w, rows_w, sparse_w)
+            return (D, T, L, it + 1, active, commits, relax, classes, kmin,
+                    fallbacks, streak, max_streak)
 
     def cond(carry):
         it, active = carry[3], carry[4]

@@ -8,8 +8,9 @@ with monotonic timestamps, recorded by every layer of the stack
 ``repro.tune`` segment loop, the serving tier's admission → flush →
 solve path).  Design constraints, in order:
 
-* **near-zero cost when off** — no tracer installed means one module-
-  global read per ``span()``/``event()`` call and a shared no-op
+* **near-zero cost when off** — no tracer installed and no profiler
+  session means one module-global read and one check of the profiler
+  per ``span()`` (a global read per ``event()``) and a shared no-op
   context manager; no allocation, no locking, no clock read.
 * **thread-safe when on** — the serving tier may pump the router from
   a different thread than the one building landmark indexes; records
@@ -19,6 +20,11 @@ solve path).  Design constraints, in order:
   so tests assert exact durations instead of sleeping.
 * **bounded** — a flight recorder must not OOM the process it
   observes; past ``max_records`` new records are dropped and counted.
+* **on the profiler's clock** — while a ``jax.profiler`` session
+  records, every span is also a ``jax.profiler.TraceAnnotation`` of
+  the same name (its scalar attributes as the annotation's arguments),
+  whether or not a :class:`Tracer` is installed, so the program's host
+  spans sit on the device trace's timeline.
 
 Usage::
 
@@ -45,6 +51,8 @@ import itertools
 import threading
 import time
 from typing import Any, Callable, Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Event",
@@ -94,12 +102,42 @@ class Event:
         return dataclasses.asdict(self)
 
 
-class SpanHandle:
+class _Annotated:
+    """The profiler half of a span: while a profiler session records,
+    a ``TraceAnnotation`` of the span's name from enter to exit, with
+    the scalar attributes known at enter as its arguments."""
+
+    __slots__ = ("name", "attrs", "_annotation")
+
+    def __init__(self, name: str, attrs: dict[str, Any]):
+        self.name = name
+        self.attrs = attrs
+        self._annotation: Optional[TraceAnnotation] = None
+
+    def set(self, **attrs: Any) -> "_Annotated":
+        self.attrs.update(attrs)
+        return self
+
+    def __enter__(self) -> "_Annotated":
+        if TraceAnnotation.is_enabled():
+            args = {k: v for k, v in self.attrs.items()
+                    if isinstance(v, (bool, int, float, str))}
+            self._annotation = TraceAnnotation(self.name, **args)
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+
+
+class SpanHandle(_Annotated):
     """Context manager for one open span.  ``set(**attrs)`` adds
     attributes any time before exit (the tune controller records its
     per-segment decision on the already-open segment span)."""
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "span_id", "parent_id")
+    __slots__ = ("_tracer", "t0", "span_id", "parent_id")
 
     def __init__(
         self,
@@ -108,22 +146,19 @@ class SpanHandle:
         attrs: dict[str, Any],
         parent_id: Optional[int],
     ):
+        super().__init__(name, attrs)
         self._tracer = tracer
-        self.name = name
-        self.attrs = attrs
         self.t0 = tracer.clock()
         self.span_id = tracer._next_id()
         self.parent_id = parent_id
 
-    def set(self, **attrs: Any) -> "SpanHandle":
-        self.attrs.update(attrs)
-        return self
-
     def __enter__(self) -> "SpanHandle":
         self._tracer._push(self)
+        super().__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        super().__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self)
@@ -294,13 +329,16 @@ def use_tracer(tracer: Optional[Tracer]) -> Iterator[Optional[Tracer]]:
 
 
 def span(name: str, **attrs: Any):
-    """Open a span on the current tracer (no-op when tracing is off).
-    Usable as a context manager; the yielded handle accepts
+    """Open a span on the current tracer; with none installed, only a
+    profiler annotation while a profiler session records, else a
+    no-op.  Usable as a context manager; the yielded handle accepts
     ``.set(**attrs)``."""
     t = _TRACER
-    if t is None:
-        return _NOOP
-    return t.span(name, **attrs)
+    if t is not None:
+        return t.span(name, **attrs)
+    if TraceAnnotation.is_enabled():
+        return _Annotated(name, attrs)
+    return _NOOP
 
 
 def event(name: str, **attrs: Any) -> None:

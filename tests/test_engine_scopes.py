@@ -1,0 +1,40 @@
+"""The engine's named scopes cover its superstep.
+
+Every op of the compiled ``while`` body, and of every computation it
+calls, whose cost can matter carries one phase (eligibility, compact,
+relax, exchange, vote), read with the parser the benchmark's per-phase
+metrics use.  An unscoped step added to ``build_step`` fails here.
+"""
+
+import pytest
+
+from bench import scopes
+from repro.api import Solver, get_processing
+from repro.core.engine import initial_state
+
+
+def engine_text(spec, graph):
+    s = Solver(spec)
+    pg = s.partition(graph)
+    fn = s.compiled(pg.n_parts, pg.n_local)
+    state = initial_state(pg, get_processing("sssp"), [])
+    return fn.lower(*pg.on_mesh(s.mesh), *state).compile().as_text()
+
+
+@pytest.mark.parametrize("exchange", ["sparse", "a2a"])
+def test_scopes_cover_the_superstep(tiny_graphs, exchange):
+    text = engine_text(f"delta:5+buffer/{exchange}", tiny_graphs[0])
+    assert scopes.unscoped(text) == []
+    body = scopes.loop_body_instructions(text)
+    costly = [i for i in body if i.opcode in scopes.COSTLY]
+    assert costly
+    # an op lowered from the step body names exactly one phase
+    in_body = [i for i in costly if "/while/body/" in i.op_name]
+    assert in_body
+    assert all(len(scopes.phases_named(i.op_name)) == 1 for i in in_body)
+    phases = set(scopes.op_phases(text).values())
+    # one device: the a2a exchange is the identity and compiles away
+    want = {"eligibility", "relax/dense", "vote"}
+    if exchange == "sparse":
+        want |= {"compact", "relax/push", "exchange"}
+    assert want <= phases

@@ -359,6 +359,48 @@ def test_solver_solve_emits_spans(tiny_graphs):
     assert all(sp.parent_id is not None for sp in segs)
     names = {e.name for e in tr.events}
     assert "engine_cache_miss" in names or "engine_cache_hit" in names
+    # the host steps of the solve, each a child of it
+    for name in ("solver.fingerprint", "solver.initial_state",
+                 "solver.unpermute"):
+        sp, = tr.find(name)
+        assert sp.parent_id == solve_span.span_id
+    # the static engine's call: dispatch through the result on the host
+    with use_tracer(tr):
+        Solver("delta:5/sparse").solve(Problem(tiny_graphs[0], SingleSource(0)))
+    engine, = tr.find("solver.engine")
+    assert tr.find("solver.solve")[-1].span_id == engine.parent_id
+
+
+def test_solver_spans_reach_the_profiler(tiny_graphs, tmp_path):
+    """With no Tracer installed, a profiler session sees the solve's
+    spans as annotations, nested on the thread that solved."""
+    import jax
+
+    from bench import xplane
+
+    s = Solver("delta:5/sparse")
+    problem = Problem(tiny_graphs[0], SingleSource(0))
+    s.solve(problem)  # compiled outside the trace
+    assert obs.current_tracer() is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        s.solve(problem)
+    finally:
+        jax.profiler.stop_trace()
+    pb, = tmp_path.glob("**/*.xplane.pb")
+    lines = [ln for p in xplane.load(pb).planes for ln in p.lines
+             if any(e.name == "solver.solve" for e in ln.events)]
+    assert len(lines) == 1
+    spans = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+             for e in lines[0].events if e.name.startswith("solver.")}
+    lo, hi = spans["solver.solve"]
+    for name in ("solver.fingerprint", "solver.initial_state",
+                 "solver.engine", "solver.unpermute"):
+        s0, s1 = spans[name]
+        assert lo <= s0 <= s1 <= hi
+    order = sorted(spans, key=spans.get)
+    assert order[1:] == ["solver.fingerprint", "solver.initial_state",
+                         "solver.engine", "solver.unpermute"]
 
 
 def test_spec_check_trace_rules():

@@ -105,3 +105,18 @@ def test_a2a_engine_on_four_chips(topo):
     )
     assert device_bytes(compiled) <= V5E_HBM_BYTES
     assert "all-to-all" in compiled.as_text()
+
+
+@pytest.mark.parametrize("exchange", ["sparse", "a2a"])
+def test_scopes_cover_the_superstep_on_four_chips(topo, exchange):
+    # the exchange's all-to-all exists only across chips
+    from bench import scopes
+
+    text = compile_engine(
+        topo.devices, exchange, n_local=1 << 10, rows=1_500, width=64
+    ).as_text()
+    assert "all-to-all" in text
+    assert scopes.unscoped(text) == []
+    assert {"eligibility", "relax/dense", "exchange", "vote"} <= set(
+        scopes.op_phases(text).values()
+    )
