@@ -253,6 +253,7 @@ def _finish_metrics(
     active=None,
     fallbacks=0,
     overflow_streak=0,
+    push_chunks=0,
 ) -> WorkMetrics:
     it = int(it)
     fallbacks = int(fallbacks)
@@ -266,6 +267,7 @@ def _finish_metrics(
         converged=converged,
         sparse_fallbacks=fallbacks,
         overflow_streak=int(overflow_streak),
+        push_chunks=int(push_chunks),
     )
     m.exchange_bytes = exchange_words(pg, ecfg, it, fallbacks) * 4 * pg.n_parts
     m.collective_rounds = it * (
@@ -283,12 +285,8 @@ def solve_with_engine_config(
     shares the facade's engine cache."""
     fn = compiled_engine(mesh, ecfg, pg.n_parts, pg.n_local)
     D0, T0, L0 = initial_state(pg, ecfg.processing, sources)
-    D, it, commits, relax, classes, active, fallbacks, streak = fn(
-        *pg.on_mesh(mesh), D0, T0, L0
-    )
-    m = _finish_metrics(
-        pg, ecfg, it, commits, relax, classes, active, fallbacks, streak
-    )
+    D, *rest = fn(*pg.on_mesh(mesh), D0, T0, L0)
+    m = _finish_metrics(pg, ecfg, *rest)
     return pg.unpermute(np.asarray(D).reshape(-1)), m
 
 
@@ -685,12 +683,12 @@ class Solver:
         fn = compiled_engine(self.mesh, ecfg, pg.n_parts, pg.n_local)
         worst = np.float32(p.worst)
         on_dev = pg.on_mesh(self.mesh)
-        D, it, commits, relax, classes, active, fallbacks, streak = fn(
-            *on_dev, D0, T0, L0
-        )
+        (D, it, commits, relax, classes, active, fallbacks, streak,
+         chunks) = fn(*on_dev, D0, T0, L0)
         it_t, commits_t = int(it), int(commits)
         relax_t, classes_t = int(relax), int(classes)
         fallbacks_t, streak_max = int(fallbacks), int(streak)
+        chunks_t = int(chunks)
         sweeps = verifies = 0
         while int(active) == 0:  # truncated runs skip repair (warned)
             padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
@@ -725,18 +723,18 @@ class Solver:
                 np.asarray(p.better(T0r, D0r)),
                 np.float32(0.0), np.float32(np.inf),
             ).astype(np.float32)
-            D, it, commits, relax, classes, active, fallbacks, streak = fn(
-                *on_dev, D0r, T0r, L0r
-            )
+            (D, it, commits, relax, classes, active, fallbacks, streak,
+             chunks) = fn(*on_dev, D0r, T0r, L0r)
             it_t += int(it)
             commits_t += int(commits)
             relax_t += int(relax)
             classes_t += int(classes)
             fallbacks_t += int(fallbacks)
             streak_max = max(streak_max, int(streak))
+            chunks_t += int(chunks)
         m = _finish_metrics(
             pg, ecfg, it_t, commits_t, relax_t, classes_t, active,
-            fallbacks_t, streak_max,
+            fallbacks_t, streak_max, chunks_t,
         )
         # each host-side re-verification sweep is one superstep's worth
         # of full-graph relaxation, moving no exchange bytes
@@ -748,12 +746,12 @@ class Solver:
 
     def _pack(
         self, problem, pg, ecfg, D, it, commits, relax, classes,
-        active=None, fallbacks=0, overflow_streak=0,
+        active=None, fallbacks=0, overflow_streak=0, push_chunks=0,
     ) -> Solution:
         padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
         m = _finish_metrics(
             pg, ecfg, it, commits, relax, classes, active, fallbacks,
-            overflow_streak,
+            overflow_streak, push_chunks,
         )
         return self._solution(problem, pg, padded, m)
 

@@ -42,7 +42,9 @@ Frontier-sparse path (``exchange='sparse'`` / ``'auto'``): instead of
 relaxing all R rows and moving O(|V|) floats, the eligible rows are
 compacted into a fixed-capacity index list (cap F, the
 ``frontier_cap`` knob; see core/frontier.py) and only those rows are
-gathered and relaxed (push mode — the Pallas realization is
+gathered and relaxed (push mode, :func:`push_relax`: chunks of K
+rows while rows are live, so the gathers and the scatter-min follow
+the live frontier, not its capacity — the Pallas realization is
 kernels/relax_push); candidates are slotted into per-destination-rank
 (idx, val) buffers of capacity S ≈ F·W/P and moved with ONE
 ``all_to_all`` — per-superstep communication scales with the frontier
@@ -55,8 +57,9 @@ identical candidate buffers, so results match the dense engine
 exactly.  The carry threads the dense-exchange superstep count out to
 :class:`repro.core.metrics.WorkMetrics` (each branch moves a
 statically known word count per superstep, so the facade reconstructs
-exact exchange bytes host-side in Python ints), plus the final active
-count for convergence/truncation detection.
+exact exchange bytes host-side in Python ints), the push relax's
+chunk trips, plus the final active count for convergence/truncation
+detection.
 """
 
 from __future__ import annotations
@@ -75,6 +78,7 @@ from repro.core.frontier import (
     compact_rows,
     frontier_caps,
     payload_plane_words,
+    push_chunk,
     sparse_payload,
     unpack_combine,
 )
@@ -204,6 +208,89 @@ def _ranks_within_pod(axis_names):
     return tuple(a for a in axis_names if a != "pod")
 
 
+def combine_into(buf, cols, vals, is_min: bool):
+    """Scatter-combine edge candidates into ``buf``: min (or max) per
+    destination; the last slot swallows ELL padding."""
+    cols, vals = cols.reshape(-1), vals.reshape(-1)
+    return buf.at[cols].min(vals) if is_min else buf.at[cols].max(vals)
+
+
+def level_into(buf, cols, cands, lvl_cands, C):
+    """Scatter-min into ``buf`` the levels of the candidates that
+    match the winning value ``C`` (the KLA deterministic tie-break)."""
+    n_pad = C.shape[0]
+    win = (
+        (lvl_cands < INF)
+        & (cands == C[jnp.clip(cols, 0, n_pad - 1)])
+        & (cols < n_pad)
+    )
+    return buf.at[cols.reshape(-1)].min(
+        jnp.where(win, lvl_cands, INF).reshape(-1)
+    )
+
+
+def push_relax(p: ProcessingFn, D, L, f_idx, f_cnt, row_src, col, wgt,
+               n_pad: int, use_level: bool):
+    """Push relax of a compacted frontier, sized by its live rows.
+
+    ``f_idx`` is :func:`~repro.core.frontier.compact_rows`'s (row_cap,)
+    list: the ``f_cnt`` live rows first, then the sentinel R.  It is
+    walked in chunks of K = :func:`~repro.core.frontier.push_chunk`
+    rows: each trip of a ``fori_loop`` with a traced bound gathers K
+    rows' columns, sources and weights (sentinel rows fill to the
+    dummy vertex and slot, so they annihilate) and scatter-combines
+    their candidates into the loop-carried (n_pad+1,) buffer.  ceil(f_cnt/K) trips cover every
+    live row, and min/max is order-free, so C is bit-identical to one
+    scatter over the whole capacity.  KLA levels take a second chunked
+    pass once C is final.
+
+    Returns ``(C, CL, trips)``: (n_pad,) candidates, their levels
+    (zeros without ``use_level``) and the chunks run.
+    """
+    R = col.shape[0]
+    n_local = D.shape[0] - 1
+    cap = f_idx.shape[0]
+    K = push_chunk(cap)
+    n_max = -(-cap // K)
+    ids = jnp.pad(f_idx, (0, n_max * K - cap), constant_values=R)
+    # f_cnt <= cap wherever the push relax is chosen; a vmapped cond
+    # runs both branches, so bound the trips for the overflowing lanes
+    # (int32 constants throughout: weak scalars in the loop fork dtypes)
+    k = jnp.int32(K)
+    trips = jnp.minimum((f_cnt + (k - 1)) // k, jnp.int32(n_max))
+    is_min = p.reduce is jnp.minimum
+
+    def rows(i):
+        f = jax.lax.dynamic_slice(ids, (i * k,), (K,))
+        colg = jnp.take(col, f, axis=0, mode="fill", fill_value=n_pad)
+        srcg = jnp.take(row_src, f, mode="fill", fill_value=n_local)
+        wgtg = jnp.take(wgt, f, axis=0, mode="fill", fill_value=jnp.inf)
+        # every gathered row is eligible (sentinel rows point at the
+        # dummy vertex, whose state is `worst`), so no masking
+        cand = jnp.broadcast_to(
+            p.edge_update(D[srcg][:, None], wgtg), wgtg.shape
+        )
+        return colg, srcg, wgtg, cand
+
+    def relax(i, buf):
+        colg, _, _, cand = rows(i)
+        return combine_into(buf, colg, cand, is_min)
+
+    buf = jnp.full((n_pad + 1,), p.worst, dtype=jnp.float32)
+    C = jax.lax.fori_loop(jnp.int32(0), trips, relax, buf)[:n_pad]
+    if not use_level:
+        return C, jnp.zeros_like(C), trips
+
+    def level(i, lbuf):
+        colg, srcg, wgtg, cand = rows(i)
+        lvl = jnp.where(wgtg < INF, (L[srcg] + 1.0)[:, None], INF)
+        return level_into(lbuf, colg, cand, lvl, C)
+
+    lbuf = jnp.full((n_pad + 1,), INF, dtype=jnp.float32)
+    CL = jax.lax.fori_loop(jnp.int32(0), trips, level, lbuf)[:n_pad]
+    return C, CL, trips
+
+
 def build_step(
     cfg: EngineConfig,
     axis_names: tuple,
@@ -230,9 +317,7 @@ def build_step(
         """Dense scatter-combine of edge candidates into a (size+1,)
         buffer (slot `size` swallows ELL padding)."""
         buf = jnp.full((size + 1,), worst, dtype=jnp.float32)
-        if is_min:
-            return buf.at[col.reshape(-1)].min(vals.reshape(-1))
-        return buf.at[col.reshape(-1)].max(vals.reshape(-1))
+        return combine_into(buf, col, vals, is_min)
 
     def reduce2(a, b):
         return p.reduce(a, b)
@@ -248,12 +333,12 @@ def build_step(
     def step(row_src, col, wgt, dyn, carry):
         if adaptive:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak,
+             fallbacks, streak, max_streak, chunks,
              pend_w, elig_w, rows_w, sparse_w) = carry
             delta_dyn, force_ex = dyn
         else:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak) = carry
+             fallbacks, streak, max_streak, chunks) = carry
         active_prev = active
         sp_used = jnp.int32(0)
         R, W = col.shape
@@ -310,22 +395,12 @@ def build_step(
             D = jnp.where(eligible, T, D)
 
         # ---- 4. relax out-edges of eligible vertices (ELL) ------------
-        def level_scatter(cols, cands, lvl_cands, C):
-            """Second scatter: min level among candidates matching the
-            winning value (deterministic tie-break)."""
-            win = (
-                (lvl_cands < INF)
-                & (cands == C[jnp.clip(cols, 0, n_pad - 1)])
-                & (cols < n_pad)
-            )
-            buf = jnp.full((n_pad + 1,), INF, dtype=jnp.float32)
-            return buf.at[cols.reshape(-1)].min(
-                jnp.where(win, lvl_cands, INF).reshape(-1)
-            )[:n_pad]
+        no_chunks = jnp.int32(0)
 
         @jax.named_scope("dense")
         def relax_dense(_):
-            """Pull sweep over all R virtual rows (masked)."""
+            """Pull sweep over all R virtual rows (masked); returns
+            (C, CL, push chunks run = 0)."""
             if is_min:
                 # §Perf(S2): semiring-implicit masking — mask at the
                 # (n_local,) vertex level and let +inf padding
@@ -347,10 +422,12 @@ def build_step(
                 cand = jnp.where(src_on[:, None] & (wgt < INF), cand, worst)
             C = scatter_reduce(col, cand, n_pad)[:n_pad]
             if not use_level:
-                return C, jnp.zeros_like(C)
+                return C, jnp.zeros_like(C), no_chunks
             live = eligible[row_src][:, None] & (wgt < INF)
             lvl_cand = jnp.where(live, (L[row_src] + 1.0)[:, None], INF)
-            return C, level_scatter(col, cand, lvl_cand, C)
+            lbuf = jnp.full((n_pad + 1,), INF, dtype=jnp.float32)
+            CL = level_into(lbuf, col, cand, lvl_cand, C)[:n_pad]
+            return C, CL, no_chunks
 
         if sparse_mode:
             with jax.named_scope("compact"):
@@ -359,11 +436,11 @@ def build_step(
 
             @jax.named_scope("push")
             def relax_push(_):
-                """Push mode: gather only the F eligible virtual rows
-                (kernels/relax_push is the TPU realization of the
+                """Push mode: gather only the eligible virtual rows,
+                in chunks of K while rows are live (push_relax;
+                kernels/relax_push is the TPU realization of the
                 gather half, kernels/superstep_fused of the whole
-                gather+relax+scatter); filled slots carry col == n_pad
-                and annihilate in the scatter."""
+                gather+relax+scatter over the full capacity)."""
                 kernel_ok = p.name == "sssp" and not use_level
                 if cfg.relax_impl.startswith("fused") and kernel_ok:
                     from repro.kernels.superstep_fused import fused_superstep
@@ -372,46 +449,32 @@ def build_step(
                         D, f_idx, f_cnt, row_src, col, wgt, n_pad,
                         interpret=_interpret_kernels(cfg.relax_impl),
                     )[:n_pad]
-                    return C, jnp.zeros_like(C)
-                colg = jnp.take(
-                    col, f_idx, axis=0, mode="fill", fill_value=n_pad
-                )
+                    return C, jnp.zeros_like(C), no_chunks
                 if cfg.relax_impl.startswith("pallas") and kernel_ok:
                     from repro.kernels.relax_push import relax_push_gather
 
+                    colg = jnp.take(
+                        col, f_idx, axis=0, mode="fill", fill_value=n_pad
+                    )
                     cand = relax_push_gather(
                         D, f_idx, f_cnt, row_src, col, wgt,
                         interpret=_interpret_kernels(cfg.relax_impl),
                     )
                     return scatter_reduce(colg, cand, n_pad)[:n_pad], \
-                        jnp.zeros((n_pad,), jnp.float32)
-                srcg = jnp.take(
-                    row_src, f_idx, mode="fill", fill_value=n_local
-                )
-                wgtg = jnp.take(
-                    wgt, f_idx, axis=0, mode="fill", fill_value=jnp.inf
-                )
-                # every gathered row is eligible (filled rows point at
-                # the dummy vertex, whose state is `worst`), so no
-                # eligibility masking is needed in push mode
-                cand = jnp.broadcast_to(
-                    p.edge_update(D[srcg][:, None], wgtg), wgtg.shape
-                )
-                C = scatter_reduce(colg, cand, n_pad)[:n_pad]
-                if not use_level:
-                    return C, jnp.zeros_like(C)
-                lvl_cand = jnp.where(
-                    wgtg < INF, (L[srcg] + 1.0)[:, None], INF
-                )
-                return C, level_scatter(colg, cand, lvl_cand, C)
+                        jnp.zeros((n_pad,), jnp.float32), no_chunks
+                return push_relax(p, D, L, f_idx, f_cnt, row_src, col,
+                                  wgt, n_pad, use_level)
 
         with jax.named_scope("relax"):
             if sparse_mode:
                 # local decision, collective-free branches: a device
                 # whose frontier overflows F sweeps densely on its own
-                C, CL = jax.lax.cond(row_overflow, relax_dense, relax_push, None)
+                C, CL, trips = jax.lax.cond(
+                    row_overflow, relax_dense, relax_push, None
+                )
+                chunks = chunks + trips
             else:
-                C, CL = relax_dense(None)
+                C, CL, _ = relax_dense(None)
 
         # ---- 5. exchange candidates to owner devices ------------------
         # Each exchange returns (mine, mineL): the combined (n_local,)
@@ -581,10 +644,10 @@ def build_step(
                 rows_w = rows_w.at[it].set(sums[1])
                 sparse_w = sparse_w.at[it].set(sp_used)
                 return (D, T, L, it + 1, active, commits, relax, classes,
-                        kmin, fallbacks, streak, max_streak,
+                        kmin, fallbacks, streak, max_streak, chunks,
                         pend_w, elig_w, rows_w, sparse_w)
             return (D, T, L, it + 1, active, commits, relax, classes, kmin,
-                    fallbacks, streak, max_streak)
+                    fallbacks, streak, max_streak, chunks)
 
     def cond(carry):
         it, active = carry[3], carry[4]
@@ -596,20 +659,22 @@ def build_step(
             jnp.int32(0), jnp.int32(1),
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.float32(jnp.nan),
-            jnp.int32(0), jnp.int32(0), jnp.int32(0),
+            jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
         )
         body = functools.partial(step, row_src, col, wgt, None)
         carry = jax.lax.while_loop(cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, _,
-         fallbacks, _streak, max_streak) = carry
+         fallbacks, _streak, max_streak, chunks) = carry
         # `active` == 0 iff the loop converged (vs. truncation at
         # max_iters); `fallbacks` = supersteps on which a
         # sparse-capable mode used the dense exchange (capacity
         # overflow, the auto pending heuristic, or the static
         # can't-pay shortcut); `max_streak` = longest run of
-        # consecutive capacity-overflow supersteps (0 in dense modes).
+        # consecutive capacity-overflow supersteps (0 in dense modes);
+        # `chunks` = push-relax chunk trips, each device counting its
+        # own and summed here once.
         return (D[:n_local], it, commits, relax, classes, active,
-                fallbacks, max_streak)
+                fallbacks, max_streak, jax.lax.psum(chunks, all_axes))
 
     def segment(row_src, col, wgt, D, T, L,
                 active0, last_key0, streak0, limit, delta_dyn, force_ex):
@@ -623,7 +688,7 @@ def build_step(
             jnp.int32(0), active0,
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             last_key0,
-            jnp.int32(0), streak0, jnp.int32(0),
+            jnp.int32(0), streak0, jnp.int32(0), jnp.int32(0),
             zw, zw, zw, zw,
         )
 
@@ -635,11 +700,12 @@ def build_step(
         )
         carry = jax.lax.while_loop(seg_cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, last_key,
-         fallbacks, streak, max_streak,
+         fallbacks, streak, max_streak, chunks,
          pend_w, elig_w, rows_w, sparse_w) = carry
         return (D, T, L, it, commits, relax, classes, active, fallbacks,
                 last_key, streak, max_streak,
-                pend_w, elig_w, rows_w, sparse_w)
+                pend_w, elig_w, rows_w, sparse_w,
+                jax.lax.psum(chunks, all_axes))
 
     return segment if adaptive else loop
 
@@ -694,7 +760,7 @@ def make_engine(
             local_seg,
             mesh=mesh,
             in_specs=(shard,) * 6 + (P(),) * 6,
-            out_specs=(shard,) * 3 + (P(),) * 13,
+            out_specs=(shard,) * 3 + (P(),) * 14,
             # the superstep body mixes per-device and replicated values in
             # while/cond carries and calls pallas_call, neither of which
             # the varying-manual-axes checker types
@@ -730,7 +796,7 @@ def make_engine(
         local,
         mesh=mesh,
         in_specs=(shard, shard, shard, shard, shard, shard),
-        out_specs=(shard,) + (P(),) * 7,
+        out_specs=(shard,) + (P(),) * 8,
         check_vma=False,  # as for the segment engine
     )
 

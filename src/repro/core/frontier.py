@@ -9,7 +9,8 @@ constraint that every shape is static:
 
 * :func:`compact_rows` — ``jnp.where``-style compaction of the eligible
   virtual-row mask into a fixed-capacity index list (cap F, overflow
-  flag for the dense fallback),
+  flag for the dense fallback), walked by the push relax in chunks of
+  :func:`push_chunk` rows,
 * :func:`bucket_slots` / :func:`scatter_plane` — per-destination-rank
   slotting of the candidate buffer into fixed-capacity (idx, val)
   buffers,
@@ -157,6 +158,24 @@ def frontier_caps(
         min(n_local // 2, (row_cap * width) // (2 * max(1, n_parts))),
     )
     return row_cap, slot_cap
+
+
+#: rows of the compacted frontier that one trip of the push relax's
+#: chunk loop gathers and scatter-mins (see :func:`push_chunk`)
+PUSH_CHUNK_ROWS = 512
+
+
+def push_chunk(row_cap: int) -> int:
+    """Static rows per trip (K) of the push relax's chunk loop.
+
+    The push relax walks the compacted frontier in chunks of K rows
+    and runs ceil(live rows / K) trips, so its gathers and scatter-min
+    follow the live frontier rather than ``row_cap``.  A scatter costs
+    per update it is handed, live or filled, so a smaller K wastes
+    fewer filled rows in the last chunk; a larger K pays the loop's
+    per-trip overhead fewer times.
+    """
+    return min(row_cap, PUSH_CHUNK_ROWS)
 
 
 def grow_frontier_cap(rows: int, cap: int) -> int:

@@ -37,6 +37,11 @@ class WorkMetrics:
     #   adaptive decisions (new frontier_cap) during this solve; 0 for
     #   static solves and for adaptive solves that only touched
     #   dynamic scalars (delta, exchange force)
+    push_chunks: int = 0  # chunks of K frontier rows the sparse push
+    #   relax gathered and scatter-combined, summed over supersteps and
+    #   devices (core.engine.push_relax); over supersteps x
+    #   ceil(row_cap / K) it is the share of a capacity-sized relax
+    #   still done.  0 for dense exchange modes and the Pallas kernels
     repair_sweeps: int = 0  # exact warm restarts the quantized-payload
     #   repair loop needed to certify the exact fixpoint (0 for exact
     #   payloads; host re-verification sweeps are folded into
@@ -56,6 +61,8 @@ class WorkMetrics:
             f"relax={self.relaxations} waste={self.waste_ratio():.2f} "
             f"xbytes={self.exchange_bytes}"
         )
+        if self.push_chunks:
+            s += f" push_chunks={self.push_chunks}"
         # anomaly fields appear only when nonzero: the one-liner stays
         # short on clean solves but never hides the events an operator
         # needs to see (dense fallbacks, adaptive retraces, quantized

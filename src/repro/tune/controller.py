@@ -106,7 +106,7 @@ def run_adaptive(
     streak = 0
 
     it_total = 0
-    commits = relax = classes = fallbacks = 0
+    commits = relax = classes = fallbacks = chunks = 0
     words = 0
     rounds = 0
     max_streak = 0
@@ -133,7 +133,8 @@ def run_adaptive(
                 np.int32(force),
             )
             (D, T, L, it_a, c_a, r_a, k_a, active_a, fb_a, lk_a,
-             streak_a, mstreak_a, pend_w, elig_w, rows_w, sparse_w) = out
+             streak_a, mstreak_a, pend_w, elig_w, rows_w, sparse_w,
+             chunks_a) = out
             it = int(it_a)
             if it == 0:
                 # can't happen while active > 0 and limit >= 1, but never
@@ -145,6 +146,7 @@ def run_adaptive(
             relax += int(r_a)
             classes += int(k_a)
             fallbacks += fb
+            chunks += int(chunks_a)
             active = int(active_a)
             last_key = np.float32(lk_a)
             streak = int(streak_a)
@@ -240,6 +242,7 @@ def run_adaptive(
         sparse_fallbacks=fallbacks,
         overflow_streak=max_streak,
         retraces=report.retraces,
+        push_chunks=chunks,
     )
     m.exchange_bytes = words * 4 * P_
     m.collective_rounds = rounds

@@ -10,6 +10,8 @@ import pytest
 
 from bench import scopes
 from repro.api import Solver, get_processing
+from repro.api.solver import engine_cache_clear
+from repro.core import frontier
 from repro.core.engine import initial_state
 
 
@@ -22,8 +24,17 @@ def engine_text(spec, graph):
 
 
 @pytest.mark.parametrize("exchange", ["sparse", "a2a"])
-def test_scopes_cover_the_superstep(tiny_graphs, exchange):
-    text = engine_text(f"delta:5+buffer/{exchange}", tiny_graphs[0])
+def test_scopes_cover_the_superstep(tiny_graphs, push_chunk_loop, exchange,
+                                    monkeypatch):
+    # chunks of 8 rows, so the tiny graph's frontier capacity takes
+    # several trips of the push relax's loop (one trip over the whole
+    # capacity is loop-invariant, and the compiler hoists its gathers)
+    monkeypatch.setattr(frontier, "PUSH_CHUNK_ROWS", 8)
+    engine_cache_clear()
+    try:
+        text = engine_text(f"delta:5+buffer/{exchange}", tiny_graphs[0])
+    finally:
+        engine_cache_clear()
     assert scopes.unscoped(text) == []
     body = scopes.loop_body_instructions(text)
     costly = [i for i in body if i.opcode in scopes.COSTLY]
@@ -38,3 +49,11 @@ def test_scopes_cover_the_superstep(tiny_graphs, exchange):
     if exchange == "sparse":
         want |= {"compact", "relax/push", "exchange"}
     assert want <= phases
+    # the push relax's chunk loop: its gathers and scatter-min, nested
+    # in the superstep, all read as relax/push
+    opcodes, loop_phases = push_chunk_loop(text)
+    if exchange == "sparse":
+        assert {"gather", "scatter"} <= opcodes
+        assert loop_phases == {"relax/push"}
+    else:
+        assert not opcodes

@@ -8,12 +8,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import Problem, SingleSource, Solver, SolverConfig
-from repro.core import dijkstra_reference, paper_variant_specs
+from repro.api import (
+    Problem, SingleSource, Solver, SolverConfig, get_processing,
+)
+from repro.api.solver import engine_cache_clear
+from repro.core import dijkstra_reference, frontier, paper_variant_specs
+from repro.core.eagm import as_hierarchy
+from repro.core.engine import push_relax
 from repro.core.frontier import (
     bucket_slots,
     compact_rows,
     frontier_caps,
+    push_chunk,
     scatter_plane,
     sparse_payload,
     unpack_combine,
@@ -98,6 +104,147 @@ def test_frontier_caps_defaults_and_knob():
     # cap clamps to the row count
     row_cap, _ = frontier_caps(16, 16, 128, 8, frontier_cap=999)
     assert row_cap == 16
+
+
+# ------------------------------------------------- chunked push relax
+
+# a (row_cap,) frontier over R ELL rows of width W; row_cap > K + 1,
+# so the capacity takes several chunks of K = push_chunk(row_cap) rows
+PUSH_R, PUSH_W, PUSH_NL, PUSH_CAP = 5_000, 4, 300, 4_500
+PUSH_K = push_chunk(PUSH_CAP)
+
+
+def push_inputs(p, f_cnt, seed=7):
+    """A random ELL (a quarter of the slots padding), states with some
+    at ``worst``, levels, and a compacted frontier of ``f_cnt`` live
+    rows padded with the sentinel R, as ``compact_rows`` leaves it."""
+    r = np.random.default_rng(seed)
+    R, W, nl = PUSH_R, PUSH_W, PUSH_NL
+    pad = r.random((R, W)) < 0.25
+    col = np.where(pad, nl, r.integers(0, nl, (R, W))).astype(np.int32)
+    wgt = np.where(pad, np.inf, r.integers(1, 100, (R, W))).astype(
+        np.float32)
+    row_src = r.integers(0, nl, R).astype(np.int32)
+    D = r.integers(0, 500, nl + 1).astype(np.float32)
+    D[r.random(nl + 1) < 0.2] = p.worst
+    D[nl] = p.worst
+    L = r.integers(0, 9, nl + 1).astype(np.float32)
+    L[nl] = np.inf
+    f_idx = np.full(PUSH_CAP, R, np.int32)
+    f_idx[:f_cnt] = np.sort(r.choice(R, f_cnt, replace=False))
+    return D, L, f_idx, row_src, col, wgt
+
+
+def push_whole_capacity(p, D, L, f_idx, row_src, col, wgt, use_level):
+    """The push relax as one gather and one scatter over the whole
+    (row_cap,) capacity, sentinel rows included."""
+    nl = D.shape[0] - 1
+    colg = jnp.take(col, f_idx, axis=0, mode="fill", fill_value=nl)
+    srcg = jnp.take(row_src, f_idx, mode="fill", fill_value=nl)
+    wgtg = jnp.take(wgt, f_idx, axis=0, mode="fill", fill_value=jnp.inf)
+    cand = jnp.broadcast_to(p.edge_update(D[srcg][:, None], wgtg),
+                            wgtg.shape)
+    buf = jnp.full((nl + 1,), p.worst, jnp.float32)
+    if p.reduce is jnp.minimum:
+        C = buf.at[colg.reshape(-1)].min(cand.reshape(-1))[:nl]
+    else:
+        C = buf.at[colg.reshape(-1)].max(cand.reshape(-1))[:nl]
+    if not use_level:
+        return C, None
+    lvl = jnp.where(wgtg < jnp.inf, (L[srcg] + 1.0)[:, None], jnp.inf)
+    win = (lvl < jnp.inf) & (cand == C[jnp.clip(colg, 0, nl - 1)]) & (
+        colg < nl)
+    lbuf = jnp.full((nl + 1,), jnp.inf, jnp.float32)
+    CL = lbuf.at[colg.reshape(-1)].min(
+        jnp.where(win, lvl, jnp.inf).reshape(-1))[:nl]
+    return C, CL
+
+
+@pytest.mark.parametrize(
+    "f_cnt", [0, 1, PUSH_K - 1, PUSH_K, PUSH_K + 1, PUSH_CAP])
+@pytest.mark.parametrize("processing,spec", [
+    ("sssp", "delta:5+buffer"), ("bfs", "delta:5+buffer"),
+    ("cc", "delta:5+buffer"), ("sswp", "delta:5+buffer"),
+    ("sssp", "kla:2+buffer"),
+])
+def test_chunked_push_matches_one_scatter(processing, spec, f_cnt):
+    """The push relax walked in chunks of K live rows gives the
+    candidate buffer (and, for KLA, the level buffer) of one scatter
+    over the whole capacity, bit for bit, in ceil(f_cnt/K) trips."""
+    p = get_processing(processing)
+    use_level = as_hierarchy(spec).needs_level
+    D, L, f_idx, row_src, col, wgt = push_inputs(p, f_cnt)
+    C, CL, trips = jax.jit(
+        lambda *a: push_relax(p, *a, n_pad=PUSH_NL, use_level=use_level)
+    )(D, L, f_idx, jnp.int32(f_cnt), row_src, col, wgt)
+    C0, CL0 = push_whole_capacity(p, D, L, f_idx, row_src, col, wgt,
+                                  use_level)
+    assert int(trips) == -(-f_cnt // PUSH_K)
+    assert np.asarray(C).tobytes() == np.asarray(C0).tobytes()
+    if use_level:
+        assert np.asarray(CL).tobytes() == np.asarray(CL0).tobytes()
+    else:
+        assert not np.asarray(CL).any()
+    if f_cnt:
+        assert (np.asarray(C) != p.worst).any()
+
+
+def host_push_chunks(pg, source, delta, K):
+    """Replay ``delta:<delta>+buffer`` on one device with numpy and
+    count the push relax's chunks: ceil(eligible rows / K) on each
+    superstep whose eligible rows fit the frontier capacity.  Returns
+    (supersteps, chunks)."""
+    row_src, col, wgt = pg.row_src[0], pg.col[0], pg.wgt[0]
+    nl, (R, W) = pg.n_local, col.shape
+    row_cap, _ = frontier_caps(R, W, nl, 1)
+    D = np.full(nl + 1, np.inf, np.float32)
+    T = D.copy()
+    T[pg.owner_slot(source)[1]] = 0.0
+    steps = chunks = 0
+    while (T < D).any():
+        pending = T < D
+        key = np.where(pending, np.floor(T / np.float32(delta)), np.inf)
+        eligible = pending & (key == key.min())
+        D = np.where(eligible, T, D)
+        rows = eligible[row_src]
+        if rows.sum() <= row_cap:
+            chunks += -(-int(rows.sum()) // K)
+        C = np.full(nl + 1, np.inf, np.float32)
+        np.minimum.at(C, col[rows].ravel(),
+                      (D[row_src[rows]][:, None] + wgt[rows]).ravel())
+        C[nl] = np.inf
+        T = np.minimum(T, C)
+        steps += 1
+    return steps, chunks
+
+
+@pytest.mark.parametrize("exchange", ["sparse", "a2a", "pmin"])
+def test_push_chunks_recounted_on_host(tiny_graphs, mesh1, monkeypatch,
+                                       exchange):
+    """``WorkMetrics.push_chunks`` is the sum over supersteps of
+    ceil(eligible rows / K), as a host replay counts it (chunks of 4
+    rows here, so supersteps take several); 0 without the push relax."""
+    g = tiny_graphs[0]
+    monkeypatch.setattr(frontier, "PUSH_CHUNK_ROWS", 4)
+    engine_cache_clear()
+    try:
+        solver = Solver(
+            SolverConfig(root="delta:5", exchange=exchange), mesh=mesh1
+        )
+        sol = solver.solve(Problem(g, SingleSource(0)))
+    finally:
+        engine_cache_clear()
+    assert close(dijkstra_reference(g, 0), sol.state)
+    m = sol.metrics
+    if exchange != "sparse":
+        assert m.push_chunks == 0 and "push_chunks" not in str(m)
+        return
+    pg = solver.partition(g)
+    row_cap, _ = frontier_caps(pg.rows_per_rank, pg.width, pg.n_local, 1)
+    steps, chunks = host_push_chunks(pg, 0, 5, push_chunk(row_cap))
+    assert steps == m.supersteps
+    assert m.push_chunks == chunks > m.supersteps
+    assert f"push_chunks={chunks}" in str(m)
 
 
 # ----------------------------------------------- dense/sparse equivalence

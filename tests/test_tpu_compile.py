@@ -108,15 +108,27 @@ def test_a2a_engine_on_four_chips(topo):
 
 
 @pytest.mark.parametrize("exchange", ["sparse", "a2a"])
-def test_scopes_cover_the_superstep_on_four_chips(topo, exchange):
-    # the exchange's all-to-all exists only across chips
+def test_scopes_cover_the_superstep_on_four_chips(topo, push_chunk_loop,
+                                                  exchange):
+    # the exchange's all-to-all exists only across chips; 20,000 rows
+    # make a frontier capacity (R/8) of several push chunks
     from bench import scopes
 
     text = compile_engine(
-        topo.devices, exchange, n_local=1 << 10, rows=1_500, width=64
+        topo.devices, exchange, n_local=1 << 10, rows=20_000, width=64
     ).as_text()
     assert "all-to-all" in text
     assert scopes.unscoped(text) == []
     assert {"eligibility", "relax/dense", "exchange", "vote"} <= set(
         scopes.op_phases(text).values()
     )
+    # the push relax's chunk loop holds no collective: each chip runs
+    # its own trip count, and its gathers and scatter-min are relax/push
+    opcodes, loop_phases = push_chunk_loop(text)
+    if exchange == "sparse":
+        assert {"gather", "scatter"} <= opcodes
+        assert loop_phases == {"relax/push"}
+        assert not opcodes & {"all-to-all", "all-reduce", "all-gather",
+                              "collective-permute"}
+    else:
+        assert not opcodes
