@@ -254,6 +254,7 @@ def _finish_metrics(
     fallbacks=0,
     overflow_streak=0,
     push_chunks=0,
+    exchange_slots=0,
 ) -> WorkMetrics:
     it = int(it)
     fallbacks = int(fallbacks)
@@ -268,6 +269,7 @@ def _finish_metrics(
         sparse_fallbacks=fallbacks,
         overflow_streak=int(overflow_streak),
         push_chunks=int(push_chunks),
+        exchange_slots=int(exchange_slots),
     )
     m.exchange_bytes = exchange_words(pg, ecfg, it, fallbacks) * 4 * pg.n_parts
     m.collective_rounds = it * (
@@ -684,11 +686,11 @@ class Solver:
         worst = np.float32(p.worst)
         on_dev = pg.on_mesh(self.mesh)
         (D, it, commits, relax, classes, active, fallbacks, streak,
-         chunks) = fn(*on_dev, D0, T0, L0)
+         chunks, slots) = fn(*on_dev, D0, T0, L0)
         it_t, commits_t = int(it), int(commits)
         relax_t, classes_t = int(relax), int(classes)
         fallbacks_t, streak_max = int(fallbacks), int(streak)
-        chunks_t = int(chunks)
+        chunks_t, slots_t = int(chunks), int(slots)
         sweeps = verifies = 0
         while int(active) == 0:  # truncated runs skip repair (warned)
             padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
@@ -724,7 +726,7 @@ class Solver:
                 np.float32(0.0), np.float32(np.inf),
             ).astype(np.float32)
             (D, it, commits, relax, classes, active, fallbacks, streak,
-             chunks) = fn(*on_dev, D0r, T0r, L0r)
+             chunks, slots) = fn(*on_dev, D0r, T0r, L0r)
             it_t += int(it)
             commits_t += int(commits)
             relax_t += int(relax)
@@ -732,9 +734,10 @@ class Solver:
             fallbacks_t += int(fallbacks)
             streak_max = max(streak_max, int(streak))
             chunks_t += int(chunks)
+            slots_t += int(slots)
         m = _finish_metrics(
             pg, ecfg, it_t, commits_t, relax_t, classes_t, active,
-            fallbacks_t, streak_max, chunks_t,
+            fallbacks_t, streak_max, chunks_t, slots_t,
         )
         # each host-side re-verification sweep is one superstep's worth
         # of full-graph relaxation, moving no exchange bytes
@@ -747,11 +750,12 @@ class Solver:
     def _pack(
         self, problem, pg, ecfg, D, it, commits, relax, classes,
         active=None, fallbacks=0, overflow_streak=0, push_chunks=0,
+        exchange_slots=0,
     ) -> Solution:
         padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
         m = _finish_metrics(
             pg, ecfg, it, commits, relax, classes, active, fallbacks,
-            overflow_streak, push_chunks,
+            overflow_streak, push_chunks, exchange_slots,
         )
         return self._solution(problem, pg, padded, m)
 

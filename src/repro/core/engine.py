@@ -58,8 +58,8 @@ exactly.  The carry threads the dense-exchange superstep count out to
 :class:`repro.core.metrics.WorkMetrics` (each branch moves a
 statically known word count per superstep, so the facade reconstructs
 exact exchange bytes host-side in Python ints), the push relax's
-chunk trips, plus the final active count for convergence/truncation
-detection.
+chunk trips, the filled slots of the sparse payloads, plus the final
+active count for convergence/truncation detection.
 """
 
 from __future__ import annotations
@@ -308,10 +308,8 @@ def build_step(
     all_axes = axis_names
     pod_axes = _ranks_within_pod(axis_names)
     sparse_mode = cfg.exchange in ("sparse", "auto")
-    # f32 planes moved by the dense exchange (values [+ KLA levels]) and
-    # by the sparse payload (values, bitcast indices [+ levels])
+    # f32 planes moved by the dense exchange (values [+ KLA levels])
     nplanes = 2 if use_level else 1
-    kplanes = 3 if use_level else 2
 
     def scatter_reduce(col, vals, size):
         """Dense scatter-combine of edge candidates into a (size+1,)
@@ -333,12 +331,12 @@ def build_step(
     def step(row_src, col, wgt, dyn, carry):
         if adaptive:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak, chunks,
+             fallbacks, streak, max_streak, chunks, slots,
              pend_w, elig_w, rows_w, sparse_w) = carry
             delta_dyn, force_ex = dyn
         else:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak, chunks) = carry
+             fallbacks, streak, max_streak, chunks, slots) = carry
         active_prev = active
         sp_used = jnp.int32(0)
         R, W = col.shape
@@ -536,7 +534,7 @@ def build_step(
                 fallbacks = fallbacks + 1
             else:  # 'sparse' | 'auto'
                 extra = [(CL, INF)] if use_level else []
-                payload, ex_overflow = sparse_payload(
+                payload, ex_overflow, ex_filled = sparse_payload(
                     C, extra, n_parts, slot_cap, worst, payload=cfg.payload
                 )
                 cap_ok = jnp.logical_not(ex_overflow)
@@ -586,6 +584,7 @@ def build_step(
                     use_sp, jnp.int32(0), jnp.int32(1)
                 )
                 sp_used = jnp.where(use_sp, jnp.int32(1), jnp.int32(0))
+                slots = slots + sp_used * ex_filled
                 streak = jnp.where(
                     overflow_g, streak + jnp.int32(1), jnp.int32(0)
                 )
@@ -644,10 +643,10 @@ def build_step(
                 rows_w = rows_w.at[it].set(sums[1])
                 sparse_w = sparse_w.at[it].set(sp_used)
                 return (D, T, L, it + 1, active, commits, relax, classes,
-                        kmin, fallbacks, streak, max_streak, chunks,
+                        kmin, fallbacks, streak, max_streak, chunks, slots,
                         pend_w, elig_w, rows_w, sparse_w)
             return (D, T, L, it + 1, active, commits, relax, classes, kmin,
-                    fallbacks, streak, max_streak, chunks)
+                    fallbacks, streak, max_streak, chunks, slots)
 
     def cond(carry):
         it, active = carry[3], carry[4]
@@ -660,21 +659,24 @@ def build_step(
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.float32(jnp.nan),
             jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
+            jnp.int32(0),
         )
         body = functools.partial(step, row_src, col, wgt, None)
         carry = jax.lax.while_loop(cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, _,
-         fallbacks, _streak, max_streak, chunks) = carry
+         fallbacks, _streak, max_streak, chunks, slots) = carry
         # `active` == 0 iff the loop converged (vs. truncation at
         # max_iters); `fallbacks` = supersteps on which a
         # sparse-capable mode used the dense exchange (capacity
         # overflow, the auto pending heuristic, or the static
         # can't-pay shortcut); `max_streak` = longest run of
         # consecutive capacity-overflow supersteps (0 in dense modes);
-        # `chunks` = push-relax chunk trips, each device counting its
-        # own and summed here once.
+        # `chunks` = push-relax chunk trips and `slots` = filled slots of
+        # the sparse payloads sent, each device counting its own and
+        # summed here once.
         return (D[:n_local], it, commits, relax, classes, active,
-                fallbacks, max_streak, jax.lax.psum(chunks, all_axes))
+                fallbacks, max_streak, jax.lax.psum(chunks, all_axes),
+                jax.lax.psum(slots, all_axes))
 
     def segment(row_src, col, wgt, D, T, L,
                 active0, last_key0, streak0, limit, delta_dyn, force_ex):
@@ -688,7 +690,7 @@ def build_step(
             jnp.int32(0), active0,
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             last_key0,
-            jnp.int32(0), streak0, jnp.int32(0), jnp.int32(0),
+            jnp.int32(0), streak0, jnp.int32(0), jnp.int32(0), jnp.int32(0),
             zw, zw, zw, zw,
         )
 
@@ -700,12 +702,12 @@ def build_step(
         )
         carry = jax.lax.while_loop(seg_cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, last_key,
-         fallbacks, streak, max_streak, chunks,
+         fallbacks, streak, max_streak, chunks, slots,
          pend_w, elig_w, rows_w, sparse_w) = carry
         return (D, T, L, it, commits, relax, classes, active, fallbacks,
                 last_key, streak, max_streak,
                 pend_w, elig_w, rows_w, sparse_w,
-                jax.lax.psum(chunks, all_axes))
+                jax.lax.psum(chunks, all_axes), jax.lax.psum(slots, all_axes))
 
     return segment if adaptive else loop
 
@@ -760,7 +762,7 @@ def make_engine(
             local_seg,
             mesh=mesh,
             in_specs=(shard,) * 6 + (P(),) * 6,
-            out_specs=(shard,) * 3 + (P(),) * 14,
+            out_specs=(shard,) * 3 + (P(),) * 15,
             # the superstep body mixes per-device and replicated values in
             # while/cond carries and calls pallas_call, neither of which
             # the varying-manual-axes checker types
@@ -796,7 +798,7 @@ def make_engine(
         local,
         mesh=mesh,
         in_specs=(shard, shard, shard, shard, shard, shard),
-        out_specs=(shard,) + (P(),) * 8,
+        out_specs=(shard,) + (P(),) * 9,
         check_vma=False,  # as for the segment engine
     )
 

@@ -15,8 +15,8 @@ constraint that every shape is static:
   slotting of the candidate buffer into fixed-capacity (idx, val)
   buffers,
 * :func:`sparse_payload` / :func:`unpack_combine` — the (P, K·S)
-  payload moved by one ``all_to_all`` (values, bitcast int32 indices
-  and, for KLA, levels as f32 planes — or u32 indices + packed 16-bit
+  u32 payload moved by one ``all_to_all`` (native indices, then the
+  values' and, for KLA, the levels' f32 bits — or packed 16-bit
   round-up value-delta codes in the quantized :data:`PAYLOAD_MODES`)
   and the owner-side scatter-combine back into a dense per-vertex
   array.
@@ -35,8 +35,8 @@ import jax.numpy as jnp
 
 INF = jnp.float32(jnp.inf)
 
-#: Sparse-exchange payload encodings.  "exact" moves f32 values +
-#: bitcast-i32 indices (bit-identical to the dense path).  "bf16" /
+#: Sparse-exchange payload encodings.  "exact" moves u32 indices + the
+#: f32 values' bits (bit-identical to the dense path).  "bf16" /
 #: "u16" move u32 indices + 16-bit quantized value *deltas* against
 #: each segment's lower bound — round-up-only, so every decoded
 #: candidate is >= the exact candidate (inflationary) and the
@@ -51,7 +51,7 @@ def payload_plane_words(
     """Axis-1 width, in 32-bit words, of one destination segment of the
     sparse all_to_all payload.
 
-    exact:     [f32 values | bitcast-i32 indices | (f32 levels)]
+    exact:     [u32 indices | bitcast-f32 values | (bitcast-f32 levels)]
     quantized: [u32 indices | packed u16-pair deltas | lo
                 | (scale, u16 only) | (bitcast-f32 levels)]
     """
@@ -203,17 +203,21 @@ def bucket_slots(mask2d: jax.Array, slot_cap: int):
     """Per-destination slot assignment for candidate compaction.
 
     ``mask2d`` (P, n_local) marks real candidates per destination rank.
-    Returns ``(slot, overflow)``: ``slot`` (P, n_local) int32 gives each
-    candidate its position within destination p's buffer (``slot_cap``
-    for non-candidates and overflow spill — a dropped slot); ``overflow``
-    True iff some destination holds more than ``slot_cap`` candidates.
+    Returns ``(slot, overflow, filled)``: ``slot`` (P, n_local) int32
+    gives each candidate its position within destination p's buffer
+    (``slot_cap`` for non-candidates and overflow spill — a dropped
+    slot); ``overflow`` True iff some destination holds more than
+    ``slot_cap`` candidates; ``filled`` the int32 count of slots taken
+    over all destinations.
     """
     pos = jnp.cumsum(mask2d.astype(jnp.int32), axis=1) - jnp.int32(1)
-    overflow = jnp.max(pos[:, -1]) + jnp.int32(1) > jnp.int32(slot_cap)
+    count = pos[:, -1] + jnp.int32(1)  # candidates per destination
+    overflow = jnp.max(count) > jnp.int32(slot_cap)
+    filled = jnp.sum(jnp.minimum(count, jnp.int32(slot_cap)))
     slot = jnp.where(
         mask2d & (pos < jnp.int32(slot_cap)), pos, jnp.int32(slot_cap)
     )
-    return slot, overflow
+    return slot, overflow, filled
 
 
 def scatter_plane(vals2d: jax.Array, slot: jax.Array, slot_cap: int, fill):
@@ -238,65 +242,63 @@ def sparse_payload(
     """Build the per-destination all_to_all payload from the (n_pad,)
     local candidate buffer ``C``.
 
-    ``payload="exact"`` (default): f32, axis-1 layout [values | bitcast
-    int32 local indices | extra planes...] — ``extra_planes`` is a list
-    of ``(array, fill)`` pairs of (n_pad,) f32 attributes riding along
-    (the KLA level).  Bit-identical to the dense exchange.
+    The payload is u32 words, axis-1 layout [indices | values | extra
+    planes...].  Indices are native integers and every f32 plane rides
+    as its bits, so nothing in transit is a float: a local index below
+    2^23 bitcast into f32 would be a denormal, which a float path that
+    flushes denormals to zero turns into index 0.  ``extra_planes`` is
+    a list of ``(array, fill)`` pairs of (n_pad,) f32 attributes riding
+    along (the KLA level).
 
-    ``payload="bf16"`` / ``"u16"``: u32, axis-1 layout [indices |
-    packed 16-bit value-delta codes | segment lower bound (+ scale for
-    u16) | bitcast extra planes...].  Indices stay full-width (the
-    payload-overflow lint's invariant: quantize values, never indices);
-    values are round-up-only deltas, so decoded candidates are >= the
-    exact ones and self-stabilization repairs them.  Requires a
-    min-reduce semiring with ``worst == +inf`` (the engine enforces).
+    ``payload="exact"`` (default): the values plane is the f32 bits of
+    the candidates — bit-identical to the dense exchange.
 
-    Returns ``(payload, overflow)``; empty slots carry ``worst`` values
+    ``payload="bf16"`` / ``"u16"``: the values plane is packed 16-bit
+    value-delta codes and the segment lower bound (+ scale for u16).
+    Indices stay full-width (the payload-overflow lint's invariant:
+    quantize values, never indices); values are round-up-only deltas,
+    so decoded candidates are >= the exact ones and self-stabilization
+    repairs them.  Requires a min-reduce semiring with ``worst == +inf``
+    (the engine enforces).
+
+    Returns ``(payload, overflow, filled)`` (:func:`bucket_slots`'
+    overflow and filled-slot count); empty slots carry ``worst`` values
     and the index sentinel n_local (the owner's discarded dummy slot).
     """
+    if payload not in PAYLOAD_MODES:
+        raise ValueError(f"unknown payload mode {payload!r}")
     Pn = n_parts
     n_local = C.shape[0] // Pn
     C2 = C.reshape(Pn, n_local)
-    slot, overflow = bucket_slots(C2 != worst, slot_cap)
+    slot, overflow, filled = bucket_slots(C2 != worst, slot_cap)
     lidx = jnp.broadcast_to(
-        jnp.arange(n_local, dtype=jnp.int32)[None, :], C2.shape
+        jnp.arange(n_local, dtype=jnp.uint32)[None, :], C2.shape
     )
-    idx_buf = scatter_plane(lidx, slot, slot_cap, jnp.int32(n_local))
+    idx_buf = scatter_plane(lidx, slot, slot_cap, jnp.uint32(n_local))
     val_buf = scatter_plane(C2, slot, slot_cap, jnp.float32(worst))
+    words = [idx_buf]
     if payload == "exact":
-        planes = [
-            val_buf,
-            jax.lax.bitcast_convert_type(idx_buf, jnp.float32),
-        ]
-        for arr, fill in extra_planes:
-            planes.append(
-                scatter_plane(
-                    arr.reshape(Pn, n_local), slot, slot_cap,
-                    jnp.float32(fill),
-                )
-            )
-        return jnp.concatenate(planes, axis=1), overflow
-    if payload not in PAYLOAD_MODES:
-        raise ValueError(f"unknown payload mode {payload!r}")
-    lo = jnp.min(val_buf, axis=1)  # per-destination-segment lower bound
-    lo_fin = jnp.where(jnp.isfinite(lo), lo, jnp.float32(0.0))
-    if payload == "bf16":
-        q = _quantize_bf16(val_buf, lo_fin)
-        head = [lo]
+        words.append(jax.lax.bitcast_convert_type(val_buf, jnp.uint32))
     else:
-        q, scale = _quantize_u16(val_buf, lo_fin)
-        head = [lo, scale]
-    words = [
-        idx_buf.astype(jnp.uint32),
-        _pack_u16_pairs(q, slot_cap),
-        jax.lax.bitcast_convert_type(jnp.stack(head, axis=1), jnp.uint32),
-    ]
+        lo = jnp.min(val_buf, axis=1)  # per-destination-segment lower bound
+        lo_fin = jnp.where(jnp.isfinite(lo), lo, jnp.float32(0.0))
+        if payload == "bf16":
+            q = _quantize_bf16(val_buf, lo_fin)
+            head = [lo]
+        else:
+            q, scale = _quantize_u16(val_buf, lo_fin)
+            head = [lo, scale]
+        words += [
+            _pack_u16_pairs(q, slot_cap),
+            jax.lax.bitcast_convert_type(jnp.stack(head, axis=1),
+                                         jnp.uint32),
+        ]
     for arr, fill in extra_planes:
         lvl_buf = scatter_plane(
             arr.reshape(Pn, n_local), slot, slot_cap, jnp.float32(fill)
         )
         words.append(jax.lax.bitcast_convert_type(lvl_buf, jnp.uint32))
-    return jnp.concatenate(words, axis=1), overflow
+    return jnp.concatenate(words, axis=1), overflow, filled
 
 
 def unpack_combine(
@@ -320,16 +322,15 @@ def unpack_combine(
     exactly the sender's reconstruction: >= the exact candidate, equal
     at each segment's lower bound.
     """
+    if payload not in PAYLOAD_MODES:
+        raise ValueError(f"unknown payload mode {payload!r}")
     S = slot_cap
+    idx = recv[:, :S].astype(jnp.int32)
     if payload == "exact":
-        val = recv[:, :S]
-        idx = jax.lax.bitcast_convert_type(recv[:, S : 2 * S], jnp.int32)
+        val = jax.lax.bitcast_convert_type(recv[:, S : 2 * S], jnp.float32)
         lvl_base = 2 * S
     else:
-        if payload not in PAYLOAD_MODES:
-            raise ValueError(f"unknown payload mode {payload!r}")
         H = (S + 1) // 2
-        idx = recv[:, :S].astype(jnp.int32)
         q = _unpack_u16_pairs(recv[:, S : S + H], S)
         lo = jax.lax.bitcast_convert_type(recv[:, S + H], jnp.float32)
         lo_fin = jnp.where(jnp.isfinite(lo), lo, jnp.float32(0.0))
@@ -355,9 +356,9 @@ def unpack_combine(
     mine = buf[:n_local]
     if not has_level:
         return mine, None
-    lvl = recv[:, lvl_base : lvl_base + S]
-    if payload != "exact":
-        lvl = jax.lax.bitcast_convert_type(lvl, jnp.float32)
+    lvl = jax.lax.bitcast_convert_type(
+        recv[:, lvl_base : lvl_base + S], jnp.float32
+    )
     win = val == buf[idx]  # sentinel slots: worst == worst, lvl fill = inf
     lbuf = jnp.full((n_local + 1,), INF, jnp.float32)
     lbuf = lbuf.at[flat_i].min(jnp.where(win, lvl, INF).reshape(-1))

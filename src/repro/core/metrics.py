@@ -42,6 +42,12 @@ class WorkMetrics:
     #   devices (core.engine.push_relax); over supersteps x
     #   ceil(row_cap / K) it is the share of a capacity-sized relax
     #   still done.  0 for dense exchange modes and the Pallas kernels
+    exchange_slots: int = 0  # candidate slots filled in the sparse
+    #   exchange payloads sent, summed over supersteps and devices; over
+    #   sparse supersteps x P^2 x slot_cap it is the live share of a
+    #   payload sized by its capacity.  0 for dense exchange modes and
+    #   on supersteps that fall back to the dense exchange.  Counted in
+    #   int32 on the devices: it wraps past 2^31 - 1 slots a solve
     repair_sweeps: int = 0  # exact warm restarts the quantized-payload
     #   repair loop needed to certify the exact fixpoint (0 for exact
     #   payloads; host re-verification sweeps are folded into
@@ -63,6 +69,8 @@ class WorkMetrics:
         )
         if self.push_chunks:
             s += f" push_chunks={self.push_chunks}"
+        if self.exchange_slots:
+            s += f" exchange_slots={self.exchange_slots}"
         # anomaly fields appear only when nonzero: the one-liner stays
         # short on clean solves but never hides the events an operator
         # needs to see (dense fallbacks, adaptive retraces, quantized

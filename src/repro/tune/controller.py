@@ -106,7 +106,7 @@ def run_adaptive(
     streak = 0
 
     it_total = 0
-    commits = relax = classes = fallbacks = chunks = 0
+    commits = relax = classes = fallbacks = chunks = slots = 0
     words = 0
     rounds = 0
     max_streak = 0
@@ -134,7 +134,7 @@ def run_adaptive(
             )
             (D, T, L, it_a, c_a, r_a, k_a, active_a, fb_a, lk_a,
              streak_a, mstreak_a, pend_w, elig_w, rows_w, sparse_w,
-             chunks_a) = out
+             chunks_a, slots_a) = out
             it = int(it_a)
             if it == 0:
                 # can't happen while active > 0 and limit >= 1, but never
@@ -147,6 +147,7 @@ def run_adaptive(
             classes += int(k_a)
             fallbacks += fb
             chunks += int(chunks_a)
+            slots += int(slots_a)
             active = int(active_a)
             last_key = np.float32(lk_a)
             streak = int(streak_a)
@@ -243,6 +244,7 @@ def run_adaptive(
         overflow_streak=max_streak,
         retraces=report.retraces,
         push_chunks=chunks,
+        exchange_slots=slots,
     )
     m.exchange_bytes = words * 4 * P_
     m.collective_rounds = rounds

@@ -55,11 +55,12 @@ def test_compact_rows_orders_and_flags_overflow():
 
 def test_bucket_slots_and_scatter_plane():
     mask = jnp.array([[True, False, True, True], [False, False, False, True]])
-    slot, overflow = bucket_slots(mask, 2)
+    slot, overflow, filled = bucket_slots(mask, 2)
     s = np.asarray(slot)
     assert s[0, 0] == 0 and s[0, 2] == 1
     assert s[0, 1] == 2 and s[0, 3] == 2  # non-candidate + spill -> dropped
     assert s[1, 3] == 0 and bool(overflow)  # row 0 holds 3 > 2 candidates
+    assert int(filled) == 3  # 2 of row 0's 3 (capped) + row 1's 1
     vals = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)
     buf = np.asarray(scatter_plane(vals, slot, 2, jnp.float32(-1.0)))
     assert buf.shape == (2, 2)
@@ -76,10 +77,11 @@ def test_payload_roundtrip_matches_dense_combine(is_min):
     C = np.full(P_ * n_local, worst, np.float32)
     hot = rng.choice(P_ * n_local, 20, replace=False)
     C[hot] = rng.uniform(1, 50, 20).astype(np.float32)
-    payload, overflow = sparse_payload(
+    payload, overflow, filled = sparse_payload(
         jnp.asarray(C), [], P_, 8, worst
     )
     assert not bool(overflow)
+    assert int(filled) == 20  # every candidate has a slot
     # single-host stand-in for all_to_all: rank r's received row p is
     # what rank p built for destination r — here every "rank" holds the
     # same C, so combining any one rank's planes against segment r

@@ -118,10 +118,10 @@ def test_quantized_payload_roundup_only(payload):
             k = int(r.integers(1, slot_cap + 1))
             hot = p * n_local + r.choice(n_local, k, replace=False)
             C[hot] = r.uniform(1.0, 50.0, k).astype(np.float32)
-        exact, ov1 = sparse_payload(jnp.asarray(C), [], P_, slot_cap,
-                                    np.float32(np.inf))
-        quant, ov2 = sparse_payload(jnp.asarray(C), [], P_, slot_cap,
-                                    np.float32(np.inf), payload=payload)
+        exact, ov1, _ = sparse_payload(jnp.asarray(C), [], P_, slot_cap,
+                                       np.float32(np.inf))
+        quant, ov2, _ = sparse_payload(jnp.asarray(C), [], P_, slot_cap,
+                                       np.float32(np.inf), payload=payload)
         assert not bool(ov1) and not bool(ov2)
         mine_e, _ = unpack_combine(
             jnp.asarray(exact), n_local, slot_cap, True,

@@ -132,3 +132,15 @@ def test_scopes_cover_the_superstep_on_four_chips(topo, push_chunk_loop,
                               "collective-permute"}
     else:
         assert not opcodes
+
+
+def test_sparse_payload_crosses_chips_as_one_integer_all_to_all(topo):
+    # one all-to-all a branch: the dense fallback's f32 candidates and
+    # the sparse payload, whose indices and value bits travel as u32
+    # words, which no float op on the way can flush to zero
+    text = compile_engine(
+        topo.devices, "sparse", n_local=1 << 12, rows=6_000, width=64
+    ).as_text()
+    types = sorted(line.split(" = ")[1].split("[")[0]
+                   for line in text.splitlines() if " all-to-all(" in line)
+    assert types == ["f32", "u32"]
