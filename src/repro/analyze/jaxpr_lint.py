@@ -148,7 +148,7 @@ def trace_step(
         local,
         mesh=mesh,
         in_specs=(spec,) * 6,
-        out_specs=(spec,) + (P(),) * 9,
+        out_specs=(spec,) + (P(),) * 10,
         check_vma=False,  # as make_engine
     )
     s = jax.ShapeDtypeStruct

@@ -235,8 +235,9 @@ def _warn_metrics(
             f"sparse exchange capacity overflowed on "
             f"{m.overflow_streak} consecutive supersteps (spec "
             f"{spec!r}: row_cap={row_cap}, slot_cap={slot_cap}), each "
-            "falling back to the dense exchange; raise frontier_cap "
-            f"(try {grow_frontier_cap(pg.rows_per_rank, row_cap)}) or "
+            "falling back to the dense relax or exchange; raise "
+            f"frontier_cap (try "
+            f"{grow_frontier_cap(pg.rows_per_rank, row_cap)}) or "
             "solve with /adapt:rho for automatic cap growth",
             RuntimeWarning,
             stacklevel=4,
@@ -255,6 +256,7 @@ def _finish_metrics(
     overflow_streak=0,
     push_chunks=0,
     exchange_slots=0,
+    exchange_local=0,
 ) -> WorkMetrics:
     it = int(it)
     fallbacks = int(fallbacks)
@@ -270,6 +272,7 @@ def _finish_metrics(
         overflow_streak=int(overflow_streak),
         push_chunks=int(push_chunks),
         exchange_slots=int(exchange_slots),
+        exchange_local=int(exchange_local),
     )
     m.exchange_bytes = exchange_words(pg, ecfg, it, fallbacks) * 4 * pg.n_parts
     m.collective_rounds = it * (
@@ -686,11 +689,11 @@ class Solver:
         worst = np.float32(p.worst)
         on_dev = pg.on_mesh(self.mesh)
         (D, it, commits, relax, classes, active, fallbacks, streak,
-         chunks, slots) = fn(*on_dev, D0, T0, L0)
+         chunks, slots, local) = fn(*on_dev, D0, T0, L0)
         it_t, commits_t = int(it), int(commits)
         relax_t, classes_t = int(relax), int(classes)
         fallbacks_t, streak_max = int(fallbacks), int(streak)
-        chunks_t, slots_t = int(chunks), int(slots)
+        chunks_t, slots_t, local_t = int(chunks), int(slots), int(local)
         sweeps = verifies = 0
         while int(active) == 0:  # truncated runs skip repair (warned)
             padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
@@ -726,7 +729,7 @@ class Solver:
                 np.float32(0.0), np.float32(np.inf),
             ).astype(np.float32)
             (D, it, commits, relax, classes, active, fallbacks, streak,
-             chunks, slots) = fn(*on_dev, D0r, T0r, L0r)
+             chunks, slots, local) = fn(*on_dev, D0r, T0r, L0r)
             it_t += int(it)
             commits_t += int(commits)
             relax_t += int(relax)
@@ -735,9 +738,10 @@ class Solver:
             streak_max = max(streak_max, int(streak))
             chunks_t += int(chunks)
             slots_t += int(slots)
+            local_t += int(local)
         m = _finish_metrics(
             pg, ecfg, it_t, commits_t, relax_t, classes_t, active,
-            fallbacks_t, streak_max, chunks_t, slots_t,
+            fallbacks_t, streak_max, chunks_t, slots_t, local_t,
         )
         # each host-side re-verification sweep is one superstep's worth
         # of full-graph relaxation, moving no exchange bytes
@@ -750,12 +754,12 @@ class Solver:
     def _pack(
         self, problem, pg, ecfg, D, it, commits, relax, classes,
         active=None, fallbacks=0, overflow_streak=0, push_chunks=0,
-        exchange_slots=0,
+        exchange_slots=0, exchange_local=0,
     ) -> Solution:
         padded = np.asarray(D).reshape(pg.n_parts, pg.n_local)
         m = _finish_metrics(
             pg, ecfg, it, commits, relax, classes, active, fallbacks,
-            overflow_streak, push_chunks, exchange_slots,
+            overflow_streak, push_chunks, exchange_slots, exchange_local,
         )
         return self._solution(problem, pg, padded, m)
 

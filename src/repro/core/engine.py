@@ -58,8 +58,12 @@ exactly.  The carry threads the dense-exchange superstep count out to
 :class:`repro.core.metrics.WorkMetrics` (each branch moves a
 statically known word count per superstep, so the facade reconstructs
 exact exchange bytes host-side in Python ints), the push relax's
-chunk trips, the filled slots of the sparse payloads, plus the final
-active count for convergence/truncation detection.
+chunk trips, the filled slots of the sparse payloads, the own
+candidates combined without them, plus the final active count for
+convergence/truncation detection.  Each device's own segment of the
+candidates never enters the sparse payload: it is combined on the
+device, so only the P-1 remote segments are packed and unpacked (on
+one device, none).
 """
 
 from __future__ import annotations
@@ -331,12 +335,12 @@ def build_step(
     def step(row_src, col, wgt, dyn, carry):
         if adaptive:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak, chunks, slots,
+             fallbacks, streak, max_streak, chunks, slots, local,
              pend_w, elig_w, rows_w, sparse_w) = carry
             delta_dyn, force_ex = dyn
         else:
             (D, T, L, it, active, commits, relax, classes, last_key,
-             fallbacks, streak, max_streak, chunks, slots) = carry
+             fallbacks, streak, max_streak, chunks, slots, local) = carry
         active_prev = active
         sp_used = jnp.int32(0)
         R, W = col.shape
@@ -533,9 +537,22 @@ def build_step(
                 mine, mineL = exchange_a2a(None)
                 fallbacks = fallbacks + 1
             else:  # 'sparse' | 'auto'
+                # the device's own segment of C never leaves it: only
+                # the P-1 remote segments are packed, voted on for
+                # overflow, sent and unpacked, and the own one seeds
+                # the combine (exact, never quantized).  On one device
+                # nothing is packed and mine is C.
+                me = _flat_rank(axis_names, mesh_shape)
+                own = jax.lax.dynamic_slice(C, (me * n_local,), (n_local,))
+                own_L = (
+                    jax.lax.dynamic_slice(CL, (me * n_local,), (n_local,))
+                    if use_level else None
+                )
+                n_own = jnp.sum((own != worst).astype(jnp.int32))
                 extra = [(CL, INF)] if use_level else []
                 payload, ex_overflow, ex_filled = sparse_payload(
-                    C, extra, n_parts, slot_cap, worst, payload=cfg.payload
+                    C, extra, n_parts, slot_cap, worst, payload=cfg.payload,
+                    self_rank=me,
                 )
                 cap_ok = jnp.logical_not(ex_overflow)
                 ok = cap_ok
@@ -571,7 +588,7 @@ def build_step(
                     )
                     mine, mineL = unpack_combine(
                         recv, n_local, slot_cap, is_min, worst, use_level,
-                        payload=cfg.payload,
+                        payload=cfg.payload, self_rank=me, own=(own, own_L),
                     )
                     if mineL is None:
                         mineL = jnp.zeros_like(mine)
@@ -585,6 +602,7 @@ def build_step(
                 )
                 sp_used = jnp.where(use_sp, jnp.int32(1), jnp.int32(0))
                 slots = slots + sp_used * ex_filled
+                local = local + sp_used * n_own
                 streak = jnp.where(
                     overflow_g, streak + jnp.int32(1), jnp.int32(0)
                 )
@@ -644,9 +662,9 @@ def build_step(
                 sparse_w = sparse_w.at[it].set(sp_used)
                 return (D, T, L, it + 1, active, commits, relax, classes,
                         kmin, fallbacks, streak, max_streak, chunks, slots,
-                        pend_w, elig_w, rows_w, sparse_w)
+                        local, pend_w, elig_w, rows_w, sparse_w)
             return (D, T, L, it + 1, active, commits, relax, classes, kmin,
-                    fallbacks, streak, max_streak, chunks, slots)
+                    fallbacks, streak, max_streak, chunks, slots, local)
 
     def cond(carry):
         it, active = carry[3], carry[4]
@@ -659,24 +677,26 @@ def build_step(
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.float32(jnp.nan),
             jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            jnp.int32(0),
+            jnp.int32(0), jnp.int32(0),
         )
         body = functools.partial(step, row_src, col, wgt, None)
         carry = jax.lax.while_loop(cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, _,
-         fallbacks, _streak, max_streak, chunks, slots) = carry
+         fallbacks, _streak, max_streak, chunks, slots, local) = carry
         # `active` == 0 iff the loop converged (vs. truncation at
         # max_iters); `fallbacks` = supersteps on which a
         # sparse-capable mode used the dense exchange (capacity
         # overflow, the auto pending heuristic, or the static
         # can't-pay shortcut); `max_streak` = longest run of
         # consecutive capacity-overflow supersteps (0 in dense modes);
-        # `chunks` = push-relax chunk trips and `slots` = filled slots of
-        # the sparse payloads sent, each device counting its own and
-        # summed here once.
+        # `chunks` = push-relax chunk trips, `slots` = filled slots of
+        # the sparse payloads sent and `local` = own candidates combined
+        # without the payload, each device counting its own and summed
+        # here once.
         return (D[:n_local], it, commits, relax, classes, active,
                 fallbacks, max_streak, jax.lax.psum(chunks, all_axes),
-                jax.lax.psum(slots, all_axes))
+                jax.lax.psum(slots, all_axes),
+                jax.lax.psum(local, all_axes))
 
     def segment(row_src, col, wgt, D, T, L,
                 active0, last_key0, streak0, limit, delta_dyn, force_ex):
@@ -691,7 +711,7 @@ def build_step(
             jnp.int32(0), jnp.int32(0), jnp.int32(0),
             last_key0,
             jnp.int32(0), streak0, jnp.int32(0), jnp.int32(0), jnp.int32(0),
-            zw, zw, zw, zw,
+            jnp.int32(0), zw, zw, zw, zw,
         )
 
         def seg_cond(c):
@@ -702,12 +722,13 @@ def build_step(
         )
         carry = jax.lax.while_loop(seg_cond, lambda c: body(c), carry)
         (D, T, L, it, active, commits, relax, classes, last_key,
-         fallbacks, streak, max_streak, chunks, slots,
+         fallbacks, streak, max_streak, chunks, slots, local,
          pend_w, elig_w, rows_w, sparse_w) = carry
         return (D, T, L, it, commits, relax, classes, active, fallbacks,
                 last_key, streak, max_streak,
                 pend_w, elig_w, rows_w, sparse_w,
-                jax.lax.psum(chunks, all_axes), jax.lax.psum(slots, all_axes))
+                jax.lax.psum(chunks, all_axes), jax.lax.psum(slots, all_axes),
+                jax.lax.psum(local, all_axes))
 
     return segment if adaptive else loop
 
@@ -762,7 +783,7 @@ def make_engine(
             local_seg,
             mesh=mesh,
             in_specs=(shard,) * 6 + (P(),) * 6,
-            out_specs=(shard,) * 3 + (P(),) * 15,
+            out_specs=(shard,) * 3 + (P(),) * 16,
             # the superstep body mixes per-device and replicated values in
             # while/cond carries and calls pallas_call, neither of which
             # the varying-manual-axes checker types
@@ -798,7 +819,7 @@ def make_engine(
         local,
         mesh=mesh,
         in_specs=(shard, shard, shard, shard, shard, shard),
-        out_specs=(shard,) + (P(),) * 9,
+        out_specs=(shard,) + (P(),) * 10,
         check_vma=False,  # as for the segment engine
     )
 

@@ -19,7 +19,9 @@ constraint that every shape is static:
   values' and, for KLA, the levels' f32 bits — or packed 16-bit
   round-up value-delta codes in the quantized :data:`PAYLOAD_MODES`)
   and the owner-side scatter-combine back into a dense per-vertex
-  array.
+  array.  Given the device's own rank, both leave its own segment out
+  (:func:`remote_ranks`): that segment travels empty and is combined
+  on the chip, exact, so on one device nothing is packed or unpacked.
 
 Everything here is collective-free local compute; the engine supplies
 the ``all_to_all`` and the global (uniform-across-ranks) fallback
@@ -212,7 +214,8 @@ def bucket_slots(mask2d: jax.Array, slot_cap: int):
     """
     pos = jnp.cumsum(mask2d.astype(jnp.int32), axis=1) - jnp.int32(1)
     count = pos[:, -1] + jnp.int32(1)  # candidates per destination
-    overflow = jnp.max(count) > jnp.int32(slot_cap)
+    # initial: no destinations (one device, own segment left out)
+    overflow = jnp.max(count, initial=0) > jnp.int32(slot_cap)
     filled = jnp.sum(jnp.minimum(count, jnp.int32(slot_cap)))
     slot = jnp.where(
         mask2d & (pos < jnp.int32(slot_cap)), pos, jnp.int32(slot_cap)
@@ -220,15 +223,28 @@ def bucket_slots(mask2d: jax.Array, slot_cap: int):
     return slot, overflow, filled
 
 
-def scatter_plane(vals2d: jax.Array, slot: jax.Array, slot_cap: int, fill):
+def scatter_plane(vals2d: jax.Array, slot: jax.Array, slot_cap: int, fill,
+                  dest=None, n_dest=None):
     """Scatter (P, n_local) values into their (P, slot_cap) buffer
-    positions; slot ``slot_cap`` is a discarded spill column."""
-    Pn = vals2d.shape[0]
-    rows = jnp.broadcast_to(
-        jnp.arange(Pn, dtype=jnp.int32)[:, None], vals2d.shape
-    )
-    buf = jnp.full((Pn, slot_cap + 1), fill, vals2d.dtype)
+    positions; slot ``slot_cap`` is a discarded spill column.
+
+    ``dest`` (rows of ``vals2d``,) names each row's buffer row among
+    ``n_dest``; rows no ``dest`` names keep ``fill``.  Default: row i
+    of ``vals2d`` fills buffer row i.
+    """
+    if dest is None:
+        dest = jnp.arange(vals2d.shape[0], dtype=jnp.int32)
+        n_dest = vals2d.shape[0]
+    rows = jnp.broadcast_to(dest[:, None], vals2d.shape)
+    buf = jnp.full((n_dest, slot_cap + 1), fill, vals2d.dtype)
     return buf.at[rows, slot].set(vals2d, mode="drop")[:, :slot_cap]
+
+
+def remote_ranks(n_parts: int, me: jax.Array) -> jax.Array:
+    """The (P-1,) ranks other than ``me``, in order: the destinations
+    whose candidates must leave the device (none on one device)."""
+    j = jnp.arange(n_parts - 1, dtype=jnp.int32)
+    return j + (j >= me).astype(jnp.int32)
 
 
 def sparse_payload(
@@ -238,6 +254,7 @@ def sparse_payload(
     slot_cap: int,
     worst,
     payload: str = "exact",
+    self_rank=None,
 ):
     """Build the per-destination all_to_all payload from the (n_pad,)
     local candidate buffer ``C``.
@@ -261,6 +278,13 @@ def sparse_payload(
     repairs them.  Requires a min-reduce semiring with ``worst == +inf``
     (the engine enforces).
 
+    ``self_rank`` (the device's flat rank, traced): its own segment of
+    ``C`` stays out of the payload — only the P-1 remote segments are
+    bucketed, counted, checked for overflow and scattered, and row
+    ``self_rank`` travels empty.  :func:`unpack_combine` with the same
+    rank combines that segment on the device instead.  ``None`` (the
+    default) packs all P segments.
+
     Returns ``(payload, overflow, filled)`` (:func:`bucket_slots`'
     overflow and filled-slot count); empty slots carry ``worst`` values
     and the index sentinel n_local (the owner's discarded dummy slot).
@@ -269,13 +293,26 @@ def sparse_payload(
         raise ValueError(f"unknown payload mode {payload!r}")
     Pn = n_parts
     n_local = C.shape[0] // Pn
-    C2 = C.reshape(Pn, n_local)
+    if self_rank is None:
+        dest = jnp.arange(Pn, dtype=jnp.int32)
+    else:
+        dest = remote_ranks(Pn, self_rank)
+
+    def segments(x):  # the (destinations, n_local) segments packed
+        x2 = x.reshape(Pn, n_local)
+        return x2 if self_rank is None else x2[dest]
+
+    C2 = segments(C)
     slot, overflow, filled = bucket_slots(C2 != worst, slot_cap)
     lidx = jnp.broadcast_to(
         jnp.arange(n_local, dtype=jnp.uint32)[None, :], C2.shape
     )
-    idx_buf = scatter_plane(lidx, slot, slot_cap, jnp.uint32(n_local))
-    val_buf = scatter_plane(C2, slot, slot_cap, jnp.float32(worst))
+
+    def plane(vals2d, fill):
+        return scatter_plane(vals2d, slot, slot_cap, fill, dest, Pn)
+
+    idx_buf = plane(lidx, jnp.uint32(n_local))
+    val_buf = plane(C2, jnp.float32(worst))
     words = [idx_buf]
     if payload == "exact":
         words.append(jax.lax.bitcast_convert_type(val_buf, jnp.uint32))
@@ -294,9 +331,7 @@ def sparse_payload(
                                          jnp.uint32),
         ]
     for arr, fill in extra_planes:
-        lvl_buf = scatter_plane(
-            arr.reshape(Pn, n_local), slot, slot_cap, jnp.float32(fill)
-        )
+        lvl_buf = plane(segments(arr), jnp.float32(fill))
         words.append(jax.lax.bitcast_convert_type(lvl_buf, jnp.uint32))
     return jnp.concatenate(words, axis=1), overflow, filled
 
@@ -309,6 +344,8 @@ def unpack_combine(
     worst,
     has_level: bool,
     payload: str = "exact",
+    self_rank=None,
+    own=None,
 ):
     """Owner-side combine of a received (P, K·S) payload.
 
@@ -317,6 +354,14 @@ def unpack_combine(
     candidates matching the winning value (the dense path's
     deterministic tie-break); ``mineL`` is None otherwise.
 
+    ``self_rank`` with ``own = (C_self, CL_self)``: row ``self_rank``
+    of ``recv`` (the device's own, empty — see :func:`sparse_payload`)
+    is skipped, and the device's own (n_local,) candidates and their
+    levels (``CL_self`` is None without ``has_level``) seed the
+    combine, so only the P-1 remote rows are scattered.  Min and max
+    are order-free, so the result is bit-identical to packing the own
+    segment too, and exact there even for quantized payloads.
+
     For quantized payloads the codes are decoded with the *same*
     expression the sender verified against, so every decoded value is
     exactly the sender's reconstruction: >= the exact candidate, equal
@@ -324,6 +369,8 @@ def unpack_combine(
     """
     if payload not in PAYLOAD_MODES:
         raise ValueError(f"unknown payload mode {payload!r}")
+    if self_rank is not None:
+        recv = recv[remote_ranks(recv.shape[0], self_rank)]
     S = slot_cap
     idx = recv[:, :S].astype(jnp.int32)
     if payload == "exact":
@@ -350,7 +397,10 @@ def unpack_combine(
                 lo_fin[:, None] + q.astype(jnp.float32) * scale[:, None],
             )
             lvl_base = S + H + 2
-    buf = jnp.full((n_local + 1,), worst, jnp.float32)
+    if self_rank is None:
+        buf = jnp.full((n_local + 1,), worst, jnp.float32)
+    else:
+        buf = jnp.concatenate([own[0], jnp.full((1,), worst, jnp.float32)])
     flat_i, flat_v = idx.reshape(-1), val.reshape(-1)
     buf = buf.at[flat_i].min(flat_v) if is_min else buf.at[flat_i].max(flat_v)
     mine = buf[:n_local]
@@ -360,6 +410,13 @@ def unpack_combine(
         recv[:, lvl_base : lvl_base + S], jnp.float32
     )
     win = val == buf[idx]  # sentinel slots: worst == worst, lvl fill = inf
-    lbuf = jnp.full((n_local + 1,), INF, jnp.float32)
+    if self_rank is None:
+        lbuf = jnp.full((n_local + 1,), INF, jnp.float32)
+    else:
+        # the own candidates' levels where they win, as their slots would
+        C_self, CL_self = own
+        own_win = (C_self == mine) & (C_self != worst)
+        lbuf = jnp.concatenate([jnp.where(own_win, CL_self, INF),
+                                jnp.full((1,), INF, jnp.float32)])
     lbuf = lbuf.at[flat_i].min(jnp.where(win, lvl, INF).reshape(-1))
     return mine, lbuf[:n_local]

@@ -28,11 +28,16 @@ class WorkMetrics:
     #                         pending work left (state is truncated)
     sparse_fallbacks: int = 0  # supersteps on which a sparse-capable
     #   exchange mode ('sparse'/'auto') used the dense path instead —
-    #   capacity overflow, the auto pending-count heuristic, or auto's
-    #   static can't-pay shortcut; 0 in plain dense modes
+    #   slot overflow toward a remote device, the auto pending-count
+    #   heuristic, or auto's static can't-pay shortcut; 0 in plain
+    #   dense modes.  A device's own candidates skip the payload and
+    #   never overflow it, so on one device no fallback comes from
+    #   overflow
     overflow_streak: int = 0  # longest run of *consecutive* supersteps
-    #   on which sparse capacity (row or slot) overflowed somewhere —
-    #   the signal behind the actionable frontier_cap RuntimeWarning
+    #   on which sparse capacity (row, or slot toward a remote device)
+    #   overflowed somewhere — the signal behind the actionable
+    #   frontier_cap RuntimeWarning; on one device only the row
+    #   capacity can overflow
     retraces: int = 0  # engine re-traces forced by shape-changing
     #   adaptive decisions (new frontier_cap) during this solve; 0 for
     #   static solves and for adaptive solves that only touched
@@ -43,11 +48,17 @@ class WorkMetrics:
     #   ceil(row_cap / K) it is the share of a capacity-sized relax
     #   still done.  0 for dense exchange modes and the Pallas kernels
     exchange_slots: int = 0  # candidate slots filled in the sparse
-    #   exchange payloads sent, summed over supersteps and devices; over
-    #   sparse supersteps x P^2 x slot_cap it is the live share of a
-    #   payload sized by its capacity.  0 for dense exchange modes and
-    #   on supersteps that fall back to the dense exchange.  Counted in
+    #   exchange payloads sent to remote devices, summed over supersteps
+    #   and devices; over sparse supersteps x P(P-1) x slot_cap it is
+    #   the live share of a payload sized by its capacity.  0 for dense
+    #   exchange modes, on supersteps that fall back to the dense
+    #   exchange, and on one device (nothing is sent).  Counted in
     #   int32 on the devices: it wraps past 2^31 - 1 slots a solve
+    exchange_local: int = 0  # candidates a device combined for its own
+    #   vertices without the payload, summed over sparse supersteps and
+    #   devices (int32, as exchange_slots); on a superstep with no slot
+    #   overflow, exchange_local + exchange_slots is every candidate of
+    #   the sparse exchange.  0 for dense exchange modes
     repair_sweeps: int = 0  # exact warm restarts the quantized-payload
     #   repair loop needed to certify the exact fixpoint (0 for exact
     #   payloads; host re-verification sweeps are folded into
@@ -71,6 +82,8 @@ class WorkMetrics:
             s += f" push_chunks={self.push_chunks}"
         if self.exchange_slots:
             s += f" exchange_slots={self.exchange_slots}"
+        if self.exchange_local:
+            s += f" exchange_local={self.exchange_local}"
         # anomaly fields appear only when nonzero: the one-liner stays
         # short on clean solves but never hides the events an operator
         # needs to see (dense fallbacks, adaptive retraces, quantized

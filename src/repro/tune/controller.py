@@ -106,7 +106,7 @@ def run_adaptive(
     streak = 0
 
     it_total = 0
-    commits = relax = classes = fallbacks = chunks = slots = 0
+    commits = relax = classes = fallbacks = chunks = slots = local = 0
     words = 0
     rounds = 0
     max_streak = 0
@@ -134,7 +134,7 @@ def run_adaptive(
             )
             (D, T, L, it_a, c_a, r_a, k_a, active_a, fb_a, lk_a,
              streak_a, mstreak_a, pend_w, elig_w, rows_w, sparse_w,
-             chunks_a, slots_a) = out
+             chunks_a, slots_a, local_a) = out
             it = int(it_a)
             if it == 0:
                 # can't happen while active > 0 and limit >= 1, but never
@@ -148,6 +148,7 @@ def run_adaptive(
             fallbacks += fb
             chunks += int(chunks_a)
             slots += int(slots_a)
+            local += int(local_a)
             active = int(active_a)
             last_key = np.float32(lk_a)
             streak = int(streak_a)
@@ -245,6 +246,7 @@ def run_adaptive(
         retraces=report.retraces,
         push_chunks=chunks,
         exchange_slots=slots,
+        exchange_local=local,
     )
     m.exchange_bytes = words * 4 * P_
     m.collective_rounds = rounds

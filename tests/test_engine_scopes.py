@@ -44,11 +44,17 @@ def test_scopes_cover_the_superstep(tiny_graphs, push_chunk_loop, exchange,
     assert in_body
     assert all(len(scopes.phases_named(i.op_name)) == 1 for i in in_body)
     phases = set(scopes.op_phases(text).values())
-    # one device: the a2a exchange is the identity and compiles away
+    # one device: either exchange is the identity and compiles away
+    # (the sparse one keeps only its votes and counters: a device's own
+    # candidates never enter the payload, and it has no others)
     want = {"eligibility", "relax/dense", "vote"}
     if exchange == "sparse":
-        want |= {"compact", "relax/push", "exchange"}
+        want |= {"compact", "relax/push"}
     assert want <= phases
+    moved = [i.opcode for instrs in scopes.parse_hlo(text).values()
+             for i in instrs if scopes.phase(i.op_name) == "exchange"
+             and i.opcode in ("scatter", "gather", "all-to-all")]
+    assert moved == []
     # the push relax's chunk loop: its gathers and scatter-min, nested
     # in the superstep, all read as relax/push
     opcodes, loop_phases = push_chunk_loop(text)

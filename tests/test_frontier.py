@@ -98,6 +98,117 @@ def test_payload_roundtrip_matches_dense_combine(is_min):
                        np.where(np.isinf(oracle), -1, oracle))
 
 
+BYPASS_P, BYPASS_NL, BYPASS_S = 4, 16, 8
+
+
+def bypass_ranks(is_min, seed, hot_per_segment):
+    """Every rank's (n_pad,) candidates and levels, as the engine makes
+    them: levels only where a candidate is, small integer values so
+    candidates of different ranks tie."""
+    r = np.random.default_rng(seed)
+    worst = np.float32(np.inf if is_min else -np.inf)
+    Cs, CLs = [], []
+    for _ in range(BYPASS_P):
+        C = np.full(BYPASS_P * BYPASS_NL, worst, np.float32)
+        for d in range(BYPASS_P):
+            hot = d * BYPASS_NL + r.choice(BYPASS_NL, hot_per_segment,
+                                           replace=False)
+            C[hot] = r.integers(1, 6, hot_per_segment)
+        CL = np.where(C != worst, r.integers(0, 4, C.shape), np.inf)
+        Cs.append(C)
+        CLs.append(CL.astype(np.float32))
+    return worst, Cs, CLs
+
+
+def exchange_at(me, Cs, CLs, worst, is_min, use_level, payload="exact",
+                bypass=True):
+    """Rank ``me``'s combine of what every rank packed for it (the
+    all_to_all's row ``me`` of each rank's payload)."""
+    extra = lambda CL: [(jnp.asarray(CL), np.inf)] if use_level else []
+    sent = [sparse_payload(jnp.asarray(C), extra(CL), BYPASS_P, BYPASS_S,
+                           worst, payload=payload,
+                           self_rank=jnp.int32(r) if bypass else None)
+            for r, (C, CL) in enumerate(zip(Cs, CLs))]
+    recv = jnp.stack([pl[me] for pl, _, _ in sent])
+    seg = slice(me * BYPASS_NL, (me + 1) * BYPASS_NL)
+    own = (jnp.asarray(Cs[me][seg]),
+           jnp.asarray(CLs[me][seg]) if use_level else None)
+    kw = dict(self_rank=jnp.int32(me), own=own) if bypass else {}
+    mine, mineL = unpack_combine(recv, BYPASS_NL, BYPASS_S, is_min, worst,
+                                 use_level, payload=payload, **kw)
+    return mine, mineL, sent
+
+
+@pytest.mark.parametrize("use_level", [False, True])
+@pytest.mark.parametrize("is_min", [True, False])
+def test_own_segment_bypass_matches_full_unpack(is_min, use_level):
+    """At P = 4 and every rank: leaving the own segment out of the
+    payload and seeding the combine with it gives today's full
+    unpack bit for bit, values and KLA levels, while the own row
+    travels empty and only remote candidates take slots."""
+    for seed in range(6):
+        worst, Cs, CLs = bypass_ranks(is_min, seed, hot_per_segment=6)
+        for me in range(BYPASS_P):
+            mine_b, lvl_b, sent_b = exchange_at(
+                me, Cs, CLs, worst, is_min, use_level)
+            mine_f, lvl_f, sent_f = exchange_at(
+                me, Cs, CLs, worst, is_min, use_level, bypass=False)
+            assert np.asarray(mine_b).tobytes() == \
+                np.asarray(mine_f).tobytes()
+            if use_level:
+                assert np.asarray(lvl_b).tobytes() == \
+                    np.asarray(lvl_f).tobytes()
+            else:
+                assert lvl_b is None and lvl_f is None
+        for r, ((pl, ov, filled), (_, ov_f, filled_f)) in enumerate(
+                zip(sent_b, sent_f)):
+            assert not bool(ov) and not bool(ov_f)
+            own_cands = int((Cs[r].reshape(BYPASS_P, -1)[r] != worst).sum())
+            assert int(filled) + own_cands == int(filled_f)
+            empty = np.asarray(pl[r])
+            assert (empty[:BYPASS_S] == BYPASS_NL).all()
+            assert (empty[BYPASS_S:2 * BYPASS_S].view(np.float32)
+                    == worst).all()
+
+
+def test_own_segment_never_overflows():
+    """The overflow vote covers remote destinations only: a rank whose
+    own segment holds more candidates than a slot capacity still sends
+    the sparse payload."""
+    worst, Cs, CLs = bypass_ranks(True, 3, hot_per_segment=2)
+    C = Cs[1].copy()
+    C[BYPASS_NL: 2 * BYPASS_NL] = 1.0  # all of rank 1's own segment
+    _, ov_b, filled_b = sparse_payload(jnp.asarray(C), [], BYPASS_P,
+                                       BYPASS_S, worst,
+                                       self_rank=jnp.int32(1))
+    _, ov_f, _ = sparse_payload(jnp.asarray(C), [], BYPASS_P, BYPASS_S,
+                                worst)
+    assert bool(ov_f) and not bool(ov_b)
+    assert int(filled_b) == 2 * (BYPASS_P - 1)
+
+
+@pytest.mark.parametrize("payload", ["bf16", "u16"])
+def test_own_segment_bypass_quantized_exact_on_own(payload):
+    """Quantized payloads: the own segment is never quantized, so the
+    combine is >= the exact one everywhere (round-up codes) and equal
+    wherever the own candidate wins; never above the own candidate."""
+    for seed in range(6):
+        worst, Cs, CLs = bypass_ranks(True, seed, hot_per_segment=6)
+        for C in Cs:  # spread values, so codes round
+            C[C != worst] *= np.float32(1.37)
+        for me in range(BYPASS_P):
+            exact, _, _ = exchange_at(me, Cs, CLs, worst, True, False)
+            quant, _, _ = exchange_at(me, Cs, CLs, worst, True, False,
+                                      payload=payload)
+            exact, quant = np.asarray(exact), np.asarray(quant)
+            own = Cs[me][me * BYPASS_NL:(me + 1) * BYPASS_NL]
+            assert np.all(quant >= exact)
+            assert np.all(quant <= own)
+            wins = own == exact
+            assert wins.any()
+            assert np.array_equal(quant[wins], exact[wins])
+
+
 def test_frontier_caps_defaults_and_knob():
     row_cap, slot_cap = frontier_caps(1024, 16, 128, 8)
     assert row_cap == 128 and slot_cap == 64  # clamped at n_local/2
@@ -291,6 +402,11 @@ def test_overflow_fallback_is_correct(tiny_graphs, mesh1):
         SolverConfig(root="delta:5", exchange="a2a"), mesh=mesh1
     ).solve(Problem(g, SingleSource(0)))
     assert sol.metrics.supersteps == dense.metrics.supersteps
+    # one device: only the row capacity overflows (a dense relax); the
+    # exchange never falls back, its candidates all stay on the device
+    m = sol.metrics
+    assert m.overflow_streak > 0 and m.sparse_fallbacks == 0
+    assert m.exchange_slots == 0 and m.exchange_local > 0
 
 
 def test_sparse_batched_sources(tiny_graphs, mesh1):

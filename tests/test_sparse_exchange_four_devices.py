@@ -21,9 +21,9 @@ import numpy as np
 import pytest
 
 ROOT = Path(__file__).resolve().parents[1]
-#: rmat1 scale 10 over four devices: a solve of about 33 supersteps of
-#: which a few overflow the exchange's slot capacity and fall back to
-#: the dense exchange, so both branches run in one solve
+#: rmat1 scale 10 over four devices: a solve of about 30 supersteps of
+#: which a few overflow the slot capacity toward another device and
+#: fall back to the dense exchange, so both branches run in one solve
 SCALE, GRAPH_SEED, SOURCES = 10, 1, (0, 17, 300)
 DELTA = 5
 SPARSE, A2A = "delta:5+buffer/sparse", "delta:5+buffer/a2a"
@@ -47,18 +47,21 @@ def flush_denormals_all_to_all(all_to_all):
 
 def replay(pg, source, delta, slot_cap):
     """Replay ``delta:<delta>+buffer`` with the sparse exchange over the
-    partition's ranks in numpy.  A superstep uses the sparse payload
-    when no rank holds more than ``slot_cap`` candidates for any one
-    destination; it then fills one slot per candidate.  Returns
-    (supersteps, dense fallbacks, filled slots, distances by padded
-    id)."""
+    partition's ranks in numpy.  A rank's candidates for its own
+    vertices never enter the payload: a superstep uses the sparse
+    payload when no rank holds more than ``slot_cap`` candidates for
+    any other rank; it then fills one slot per remote candidate and
+    combines the own ones on the rank.  Returns (supersteps, dense
+    fallbacks, filled slots, own candidates combined, distances by
+    padded id)."""
     P_, nl = pg.n_parts, pg.n_local
     n_pad = P_ * nl
     D = np.full((P_, nl + 1), np.inf, np.float32)
     T = D.copy()
     r0, j0 = pg.owner_slot(source)
     T[int(r0), int(j0)] = 0.0
-    steps = fallbacks = slots = 0
+    steps = fallbacks = slots = local = 0
+    remote = ~np.eye(P_, dtype=bool)
     while (T < D).any():
         pending = T < D
         key = np.where(pending, np.floor(T / np.float32(delta)), np.inf)
@@ -72,14 +75,15 @@ def replay(pg, source, delta, slot_cap):
                 (D[r][pg.row_src[r][rows]][:, None]
                  + pg.wgt[r][rows]).ravel())
         per_dest = (C[:, :n_pad] != np.inf).reshape(P_, P_, nl).sum(2)
-        if per_dest.max() > slot_cap:
+        if per_dest[remote].max() > slot_cap:
             fallbacks += 1
         else:
-            slots += int(per_dest.sum())
+            slots += int(per_dest[remote].sum())
+            local += int(np.trace(per_dest))
         mine = C[:, :n_pad].reshape(P_, P_, nl).min(0)
         T[:, :nl] = np.minimum(T[:, :nl], mine)
         steps += 1
-    return steps, fallbacks, slots, D[:, :nl].reshape(-1)
+    return steps, fallbacks, slots, local, D[:, :nl].reshape(-1)
 
 
 @pytest.fixture(scope="module")
@@ -134,6 +138,20 @@ def test_exchange_slots_recounted_on_host(four_devices):
         assert host["exact"], src
 
 
+def test_exchange_local_recounted_on_host(four_devices):
+    """``WorkMetrics.exchange_local`` is the number of candidates each
+    rank combined for its own vertices without the payload, summed over
+    sparse supersteps and ranks, as the replay counts them; with the
+    slots it makes every candidate of the sparse supersteps."""
+    for src, runs in four_devices["plain"].items():
+        sp, host = runs[SPARSE], four_devices["replay"][src]
+        assert sp.get("exchange_local") == host["local"] > 0, src
+        assert f"exchange_local={host['local']}" in sp["str"], src
+        assert sp["exchange_local"] + sp["exchange_slots"] == \
+            host["local"] + host["slots"], src
+        assert runs[A2A].get("exchange_local") == 0, src
+
+
 def child() -> None:
     import jax
 
@@ -180,10 +198,10 @@ def child() -> None:
                                 pg.n_parts)
     result["replay"] = {}
     for s in SOURCES:
-        steps, fallbacks, slots, d = replay(pg, s, DELTA, slot_cap)
+        steps, fallbacks, slots, local, d = replay(pg, s, DELTA, slot_cap)
         result["replay"][s] = dict(
             supersteps=steps, fallbacks=fallbacks, slots=slots,
-            exact=same(pg.unpermute(d), ref[s]))
+            local=local, exact=same(pg.unpermute(d), ref[s]))
     print(json.dumps(result))
 
 

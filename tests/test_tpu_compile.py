@@ -12,6 +12,7 @@ collection would stop the others from collecting the same tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.engine import EngineConfig, make_engine
+from repro.core.frontier import frontier_caps
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -144,3 +146,41 @@ def test_sparse_payload_crosses_chips_as_one_integer_all_to_all(topo):
     types = sorted(line.split(" = ")[1].split("[")[0]
                    for line in text.splitlines() if " all-to-all(" in line)
     assert types == ["f32", "u32"]
+
+
+def scatters(text):
+    """(result elements, updates shape, op_name) of every scatter in a
+    compiled module, read from the HLO types of its operands."""
+    shapes = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = \w+\[([\d,]*)\]", line)
+        if m:
+            shapes[m.group(1)] = tuple(
+                int(d) for d in m.group(2).split(",") if d)
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"%([\w.\-]+) = \w+\[[^\]]*\]\S* scatter\(([^)]*)\)",
+                      line)
+        if m:
+            updates = m.group(2).split(",")[2].strip().lstrip("%")
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out.append((int(np.prod(shapes[m.group(1)])), shapes[updates],
+                        op_name.group(1) if op_name else ""))
+    return out
+
+
+def test_sparse_exchange_scatters_remote_segments_only(topo):
+    # each chip's own candidates stay out of the payload: the pack's
+    # two planes (indices, value bits), each of P x (slot_cap + 1)
+    # words, are handed the 3 remote segments of n_local candidates,
+    # and the unpack's scatter-min the 3 received segments' slots
+    P_, n_local, rows, width = 4, 1 << 12, 6_000, 64
+    _, slot_cap = frontier_caps(rows, width, n_local, P_)
+    found = scatters(compile_engine(
+        topo.devices, "sparse", n_local=n_local, rows=rows, width=width
+    ).as_text())
+    pack = [u for n, u, _ in found if n == P_ * (slot_cap + 1)]
+    assert pack == [(P_ - 1, n_local)] * 2
+    unpack = [u for n, u, op in found
+              if n == n_local + 1 and "/exchange/" in op]
+    assert [int(np.prod(u)) for u in unpack] == [(P_ - 1) * slot_cap]
